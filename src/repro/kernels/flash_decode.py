@@ -27,10 +27,11 @@ this module). What differs per layout is a ``(index_maps, loader)`` pair:
   repeats, so dead tiles generate no HBM traffic. Tiered layouts route
   each step's DMA to exactly one tier (the untaken tier's map clamps onto
   the garbage page, repeated index, DMA elided).
-* the **loader** turns the fetched refs into f32 ``(bs, dh)`` K/V tiles —
-  a plain read for fp layouts, an in-VMEM dequantization (rounded through
-  the serving dtype so kernel and einsum oracle agree to f32 roundoff) for
-  the int8 tiers, a fetch-once/use-twice split for the MLA latent pool.
+* the **loader** turns the fetched refs into f32 head-major
+  ``(Hkv, bs, dh)`` K/V tiles — a plain read for fp layouts, an in-VMEM
+  dequantization (rounded through the serving dtype so kernel and einsum
+  oracle agree to f32 roundoff) for the int8 tiers, a fetch-once/use-twice
+  split for the MLA latent pool.
 
 The pairs are what ``runtime/layouts.py``'s :class:`CacheLayout` registry
 hands out — each cache layout owns its kernel entrypoint here, and nothing
@@ -62,11 +63,15 @@ Layout family notes:
   the fp latent pool. Same hotness rule, same tier routing in the index
   maps, same fetch-once/use-twice split as the fp MLA kernel.
 
-Grid: (B, Hkv, S/bs) with S innermost ("arbitrary"); each (b, h) cell
-keeps the GQA query group (G = H // Hkv queries) resident and reduces over
-the key tiles. B and Hkv are parallel. The MLA kernels degenerate the Hkv
-axis to 1 (the latent cache is shared by every head) and keep all H
-queries resident in the one cell.
+Grid: (B, S/bs) with S innermost ("arbitrary"); each step fetches one
+whole key tile across every KV head — a ``(1, bs, Hkv, dh)`` block, whose
+last two dims equal the pool's, which is what Mosaic's (8, 128) tiling
+rule asks of a block — and reduces all heads at once in head-batched dots
+against the resident ``(Hkv, G, dh)`` query block (GQA group G = H // Hkv).
+B is parallel. The MLA kernels degenerate the head axis to 1 (the latent
+cache is shared by every head) and keep all H queries resident in the
+one cell. Per-page int8 scales ride as ``(P, Hkv, 1)`` / ``(P, 1, 1)``
+VMEM blocks for the same tiling reason.
 
 CPU CI runs these same kernel bodies with ``interpret=True``.
 """
@@ -80,9 +85,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 DEFAULT_BS = 512          # key-tile length along the cache S axis
+TILE_ELEMS = 1 << 18      # K/V tile elements (all heads) per contiguous step
 NEG_INF = float('-inf')
 
 
@@ -99,14 +103,22 @@ def _live_block_range(pos, win, bs: int):
     return first, last
 
 
+def _head_major(x):
+    """(bs, Hkv, d) fetched page tile -> (Hkv, bs, d): the batch-leading
+    layout the head-batched dots take."""
+    return jnp.swapaxes(x, 0, 1)
+
+
 def _softmax_tile(pos, win, s, q_ref, load_kv, o_ref,
                   acc_ref, m_ref, l_ref, *, bs: int, s_steps: int,
                   scale: float, chunk: int = 1, group: int = 1, off=None):
     """One online-softmax step over key tile ``s`` — THE compute path every
     flash-decode entrypoint reduces through; only the scalar plumbing and
     the K/V tile loader differ per layout. ``load_kv() -> (k, v)`` f32
-    (bs, dh) tiles; it runs under the live-tile predicate so dead steps
-    skip both the load and the compute.
+    head-major ``(Hg, bs, d)`` tiles (Hg = the KV heads of the block, 1 for
+    the MLA latent); it runs under the live-tile predicate so dead steps
+    skip both the load and the compute. Every head of the block reduces in
+    one head-batched pair of dots against its ``(Hg, rows, dk)`` queries.
 
     ``chunk > 1`` is the chunked-prefill shape: the resident query rows
     cover ``chunk`` consecutive positions (``group`` query heads each,
@@ -128,20 +140,20 @@ def _softmax_tile(pos, win, s, q_ref, load_kv, o_ref,
 
     @pl.when(live)
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (G, dh)
-        k, v = load_kv()                                     # (bs, dh) f32
-        kpos = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        q = q_ref[0].astype(jnp.float32)                     # (Hg, rows, dk)
+        k, v = load_kv()                                     # (Hg, bs, d)
+        kpos = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
         if chunk == 1:
             valid = (kpos <= pos) & (kpos > pos - win)
         else:
             rows = q_ref.shape[2]
             qp = off + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, 1), 0) // group            # (rows, 1)
+                jnp.int32, (1, rows, 1), 1) // group         # (1, rows, 1)
             row_win = win - (chunk - 1)
-            valid = (kpos <= qp) & (kpos > qp - row_win)     # (rows, bs)
+            valid = (kpos <= qp) & (kpos > qp - row_win)     # (1, rows, bs)
         logits = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (G, bs)
+            q, k, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale      # (Hg, rows, bs)
         logits = jnp.where(valid, logits, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
@@ -151,14 +163,14 @@ def _softmax_tile(pos, win, s, q_ref, load_kv, o_ref,
         p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
+            p, v, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(s == s_steps - 1)
     def _epilogue():
         # the one output conversion: normalize once, after the full reduction
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 # ----------------------------------------------------------------------------
@@ -179,7 +191,7 @@ def _core_kernel(*refs, ns: int, nt: int, loader, bs: int, s_steps: int,
     t_refs = refs[ns + 1:ns + 1 + nt]
     o_ref, acc_ref, m_ref, l_ref = refs[ns + 1 + nt:]
     b = pl.program_id(0)
-    s = pl.program_id(2)
+    s = pl.program_id(1)
     pos, win = scalars[0][b], scalars[1][b]
     off = scalars[off_idx][b] if off_idx is not None else None
     load_kv = loader(scalars, t_refs, b, s, pos, win)
@@ -192,28 +204,32 @@ def _flash_core(q: jnp.ndarray, scalars, tensors, tensor_specs, *, loader,
                 out_width: int, bs: int, s_steps: int, scale: float,
                 interpret: bool, chunk: int = 1, group: int = 1,
                 off_idx=None) -> jnp.ndarray:
-    """Run the flash-decode grid over ``q`` (B, Hgrid, G, dk) with a
+    """Run the flash-decode grid over ``q`` (B, Hg, rows, dk) with a
     layout-supplied ``(index_maps, loader)`` pair: ``tensor_specs`` carry
     the layout's data-dependent index maps (one BlockSpec per tensor
-    operand), ``loader`` turns the fetched refs into f32 K/V tiles.
-    ``scalars`` ride in scalar-prefetch operands (pos and window first —
-    the core reads those itself). Returns (B, Hgrid, G, out_width) f32."""
-    b, hgrid, g, dk = q.shape
-    grid = (b, hgrid, s_steps)
+    operand, each fetching a whole page/tile across every head, so the
+    last two block dims always equal the array's — the Mosaic tiling
+    rule), ``loader`` turns the fetched refs into f32 head-major K/V
+    tiles. ``scalars`` ride in scalar-prefetch operands (pos and window
+    first — the core reads those itself). Returns (B, Hg, rows, out_width)
+    f32."""
+    b, hg, rows, dk = q.shape
+    grid = (b, s_steps)
 
-    def qo_map(bb, h, s, *sr):
+    def qo_map(bb, s, *sr):
         del s, sr
-        return (bb, h, 0, 0)
+        return (bb, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 1, g, dk), qo_map)] + list(tensor_specs),
-        out_specs=pl.BlockSpec((1, 1, g, out_width), qo_map),
+        in_specs=[pl.BlockSpec((1, hg, rows, dk), qo_map)]
+        + list(tensor_specs),
+        out_specs=pl.BlockSpec((1, hg, rows, out_width), qo_map),
         scratch_shapes=[
-            pltpu.VMEM((g, out_width), jnp.float32),  # unnormalized output
-            pltpu.VMEM((g, 1), jnp.float32),          # running max
-            pltpu.VMEM((g, 1), jnp.float32),          # running sum
+            pltpu.VMEM((hg, rows, out_width), jnp.float32),  # unnormalized
+            pltpu.VMEM((hg, rows, 1), jnp.float32),          # running max
+            pltpu.VMEM((hg, rows, 1), jnp.float32),          # running sum
         ],
     )
     return pl.pallas_call(
@@ -221,9 +237,10 @@ def _flash_core(q: jnp.ndarray, scalars, tensors, tensor_specs, *, loader,
                           loader=loader, bs=bs, s_steps=s_steps, scale=scale,
                           chunk=chunk, group=group, off_idx=off_idx),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hgrid, g, out_width), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+        out_shape=jax.ShapeDtypeStruct((b, hg, rows, out_width),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary'),
         ),
         interpret=interpret,
     )(*scalars, q, *tensors)
@@ -236,12 +253,13 @@ def _clamped_block(s, pos_ref, win_ref, b, bs: int):
     return jnp.clip(s, first, last)
 
 
-def _fp_loader(t_refs):
-    """Plain fp K/V loader (contiguous and paged layouts): read the two
-    fetched refs into f32."""
+def _fp_loader(scalars, t_refs, bb, s, pos, win):
+    """Plain fp K/V loader (contiguous and paged GQA layouts): the fetched
+    (1, bs, Hkv, dh) blocks, read into f32 head-major tiles."""
+    del scalars, bb, s, pos, win
     k_ref, v_ref = t_refs
-    return lambda: (k_ref[0, :, 0, :].astype(jnp.float32),
-                    v_ref[0, :, 0, :].astype(jnp.float32))
+    return lambda: (_head_major(k_ref[0].astype(jnp.float32)),
+                    _head_major(v_ref[0].astype(jnp.float32)))
 
 
 # ----------------------------------------------------------------------------
@@ -278,20 +296,20 @@ def flash_decode_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     s_steps = s_max // bs
 
     if prefetch:
-        def kv_map(bb, h, s, pos_ref, win_ref):
-            return (bb, _clamped_block(s, pos_ref, win_ref, bb, bs), h, 0)
+        def kv_map(bb, s, pos_ref, win_ref):
+            return (bb, _clamped_block(s, pos_ref, win_ref, bb, bs), 0, 0)
     else:
-        def kv_map(bb, h, s, pos_ref, win_ref):
+        def kv_map(bb, s, pos_ref, win_ref):
             del pos_ref, win_ref
-            return (bb, s, h, 0)
+            return (bb, s, 0, 0)
 
     return _flash_core(
         q,
         scalars=(pos.astype(jnp.int32), window.astype(jnp.int32)),
         tensors=(k, v),
-        tensor_specs=[pl.BlockSpec((1, bs, 1, dh), kv_map),
-                      pl.BlockSpec((1, bs, 1, dh), kv_map)],
-        loader=lambda scalars, t_refs, bb, s, pos_, win_: _fp_loader(t_refs),
+        tensor_specs=[pl.BlockSpec((1, bs, hkv, dh), kv_map),
+                      pl.BlockSpec((1, bs, hkv, dh), kv_map)],
+        loader=_fp_loader,
         out_width=dh, bs=bs, s_steps=s_steps, scale=scale,
         interpret=interpret)
 
@@ -310,7 +328,8 @@ def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     """Single-token GQA decode attention over a *paged* KV pool.
 
     q:            (B, Hkv, G, dh)
-    k/v_pages:    (P, page_size, Hkv, dh) — pool shared by all requests
+    k/v_pages:    (P, page_size, Hkv, dh) — pool shared by all requests;
+                  each grid step fetches one whole page, every head
     pos:          (B,) int32 per-request absolute position
     window:       (B,) int32 per-request sliding window
     block_tables: (B, W) int32 — logical key block i of request b lives in
@@ -337,21 +356,22 @@ def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     assert (offset is None) == (chunk == 1), (offset, chunk)
     s_steps = block_tables.shape[1]
 
-    def kv_map(bb, h, s, pos_ref, win_ref, bt_ref, *rest):
+    def kv_map(bb, s, pos_ref, win_ref, bt_ref, *rest):
         blk = _clamped_block(s, pos_ref, win_ref, bb, page_size)
-        return (bt_ref[bb, blk], 0, h, 0)
+        return (bt_ref[bb, blk], 0, 0, 0)
 
     scalars = (pos.astype(jnp.int32), window.astype(jnp.int32),
                block_tables.astype(jnp.int32))
     if chunk > 1:
         scalars = scalars + (offset.astype(jnp.int32),)
+    kv_block = (1, page_size, hkv, dh)
     return _flash_core(
         q,
         scalars=scalars,
         tensors=(k_pages, v_pages),
-        tensor_specs=[pl.BlockSpec((1, page_size, 1, dh), kv_map),
-                      pl.BlockSpec((1, page_size, 1, dh), kv_map)],
-        loader=lambda scalars, t_refs, bb, s, pos_, win_: _fp_loader(t_refs),
+        tensor_specs=[pl.BlockSpec(kv_block, kv_map),
+                      pl.BlockSpec(kv_block, kv_map)],
+        loader=_fp_loader,
         out_width=dh, bs=page_size, s_steps=s_steps, scale=scale,
         interpret=interpret, chunk=chunk, group=g // chunk,
         off_idx=3 if chunk > 1 else None)
@@ -407,8 +427,7 @@ def flash_decode_mla_paged(q: jnp.ndarray, c_pages: jnp.ndarray,
     assert (offset is None) == (chunk == 1), (offset, chunk)
     s_steps = block_tables.shape[1]
 
-    def c_map(bb, g_, s, pos_ref, win_ref, bt_ref, *rest):
-        del g_
+    def c_map(bb, s, pos_ref, win_ref, bt_ref, *rest):
         blk = _clamped_block(s, pos_ref, win_ref, bb, page_size)
         return (bt_ref[bb, blk], 0, 0)
 
@@ -417,8 +436,8 @@ def flash_decode_mla_paged(q: jnp.ndarray, c_pages: jnp.ndarray,
 
         def load():
             # fetch once, use twice: full width = keys, first r cols = values
-            lat = c_ref[0].astype(jnp.float32)         # (bs, r + d_rope)
-            return lat, lat[:, :r]
+            lat = c_ref[...].astype(jnp.float32)       # (1, bs, r + d_rope)
+            return lat, lat[:, :, :r]
         return load
 
     scalars = (pos.astype(jnp.int32), window.astype(jnp.int32),
@@ -448,26 +467,42 @@ def _blk_hot(bb, s, pos_ref, win_ref, hw_ref, bs: int):
     return blk, blk > last - hw_ref[0]
 
 
-def _tier_maps(page_size: int):
-    """(fp_map, q8_map, scale_map) index-map factories for a paged
-    hot/cold tier pair: a hot step fetches the fp page and parks the int8
-    fetch on the garbage page (repeated index, DMA elided); a cold step
-    does the reverse. ``scale_map`` follows the cold tier with a trailing
-    per-page axis (the head axis for GQA, the single absmax column for
-    MLA)."""
-    def fp_map(bb, h, s, pos_ref, win_ref, bt_ref, hw_ref):
-        blk, hot = _blk_hot(bb, s, pos_ref, win_ref, hw_ref, page_size)
-        return (jnp.where(hot, bt_ref[bb, blk], 0), 0, h, 0)
+def _tier_maps(page_size: int, pool_ndim: int):
+    """(fp_map, q8_map, scale_map) index maps for a paged hot/cold tier
+    pair of rank-``pool_ndim`` pools: a hot step fetches the fp page and
+    parks the int8 fetch on the garbage page (repeated index, DMA elided);
+    a cold step does the reverse. ``scale_map`` follows the cold tier over
+    the rank-3 per-page scale operand (``(P, Hkv, 1)`` for GQA,
+    ``(P, 1, 1)`` for MLA), one whole page's scales per step."""
+    zeros = (0,) * (pool_ndim - 1)
 
-    def q8_map(bb, h, s, pos_ref, win_ref, bt_ref, hw_ref):
+    def fp_map(bb, s, pos_ref, win_ref, bt_ref, hw_ref):
         blk, hot = _blk_hot(bb, s, pos_ref, win_ref, hw_ref, page_size)
-        return (jnp.where(hot, 0, bt_ref[bb, blk]), 0, h, 0)
+        return (jnp.where(hot, bt_ref[bb, blk], 0),) + zeros
 
-    def scale_map(bb, h, s, pos_ref, win_ref, bt_ref, hw_ref):
+    def q8_map(bb, s, pos_ref, win_ref, bt_ref, hw_ref):
         blk, hot = _blk_hot(bb, s, pos_ref, win_ref, hw_ref, page_size)
-        return (jnp.where(hot, 0, bt_ref[bb, blk]), h)
+        return (jnp.where(hot, 0, bt_ref[bb, blk]),) + zeros
+
+    def scale_map(bb, s, pos_ref, win_ref, bt_ref, hw_ref):
+        blk, hot = _blk_hot(bb, s, pos_ref, win_ref, hw_ref, page_size)
+        return (jnp.where(hot, 0, bt_ref[bb, blk]), 0, 0)
 
     return fp_map, q8_map, scale_map
+
+
+def _hot_now(scalars, s, pos, win, page_size: int):
+    """The loader's side of the hotness rule (same clamp as the maps)."""
+    first, last = _live_block_range(pos, win, page_size)
+    return jnp.clip(s, first, last) > last - scalars[3][0]
+
+
+def _dequant(q_tile, scale, like_ref):
+    """The one dequantization per fetched int8 tile, rounded through the
+    serving dtype so the tier mix is bit-identical with the einsum
+    oracle's ``dequant_gather``."""
+    return (q_tile.astype(jnp.float32) * scale) \
+        .astype(like_ref.dtype).astype(jnp.float32)
 
 
 @functools.partial(jax.jit,
@@ -495,56 +530,52 @@ def flash_decode_gqa_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
     Block ``s`` of a request at ``pos`` is hot iff
     ``s > pos // page_size - hot_window``; a hot grid step fetches the fp
     page and clamps the int8 fetch onto the garbage page (and vice versa),
-    so each tile pays one tier's HBM bytes, never both.
+    so each tile pays one tier's HBM bytes, never both. The scales ride
+    as (P, Hkv, 1) VMEM blocks — one page's per-head column per step,
+    broadcast along the fetched tile's head axis.
     """
     b, hkv, g, dh = q.shape
-    _, page_size, hkv_k, dh_k = k_pages.shape
+    n_pages, page_size, hkv_k, dh_k = k_pages.shape
     assert (hkv_k, dh_k) == (hkv, dh), (q.shape, k_pages.shape)
     assert v_pages.shape == k_pages.shape
     assert kq_pages.shape == k_pages.shape and kq_pages.dtype == jnp.int8
     assert vq_pages.shape == k_pages.shape and vq_pages.dtype == jnp.int8
-    assert k_scales.shape == v_scales.shape == k_pages.shape[:1] + (hkv,)
+    assert k_scales.shape == v_scales.shape == (n_pages, hkv)
     assert pos.shape == (b,) and window.shape == (b,)
     assert block_tables.ndim == 2 and block_tables.shape[0] == b
     assert hot_window.shape == (1,)
     s_steps = block_tables.shape[1]
-    fp_map, q8_map, scale_map = _tier_maps(page_size)
+    fp_map, q8_map, scale_map = _tier_maps(page_size, 4)
 
     def q8_loader(scalars, t_refs, bb, s, pos_, win_):
         k_ref, v_ref, kq_ref, vq_ref, ks_ref, vs_ref = t_refs
-        hw_ref = scalars[3]
-        first, last = _live_block_range(pos_, win_, page_size)
-        hot = jnp.clip(s, first, last) > last - hw_ref[0]
+        hot = _hot_now(scalars, s, pos_, win_, page_size)
 
         def load():
-            k_fp = k_ref[0, :, 0, :].astype(jnp.float32)
-            v_fp = v_ref[0, :, 0, :].astype(jnp.float32)
-            # the one dequantization per fetched tile (scales are per-page,
-            # per-head, so one scalar covers the whole (bs, dh) tile);
-            # round through the serving dtype so the tier mix is
-            # bit-identical with the dequant_gather einsum oracle
-            k_q8 = (kq_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, 0]) \
-                .astype(k_ref.dtype).astype(jnp.float32)
-            v_q8 = (vq_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, 0]) \
-                .astype(v_ref.dtype).astype(jnp.float32)
-            return (jnp.where(hot, k_fp, k_q8), jnp.where(hot, v_fp, v_q8))
+            k = jnp.where(hot, k_ref[0].astype(jnp.float32),
+                          _dequant(kq_ref[0], ks_ref[...], k_ref))
+            v = jnp.where(hot, v_ref[0].astype(jnp.float32),
+                          _dequant(vq_ref[0], vs_ref[...], v_ref))
+            return _head_major(k), _head_major(v)
         return load
 
-    kv_block = (1, page_size, 1, dh)
+    kv_block = (1, page_size, hkv, dh)
+    sc_block = (1, hkv, 1)
     return _flash_core(
         q,
         scalars=(pos.astype(jnp.int32), window.astype(jnp.int32),
                  block_tables.astype(jnp.int32),
                  hot_window.astype(jnp.int32)),
         tensors=(k_pages, v_pages, kq_pages, vq_pages,
-                 k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)),
+                 k_scales.astype(jnp.float32).reshape(n_pages, hkv, 1),
+                 v_scales.astype(jnp.float32).reshape(n_pages, hkv, 1)),
         tensor_specs=[
             pl.BlockSpec(kv_block, fp_map),
             pl.BlockSpec(kv_block, fp_map),
             pl.BlockSpec(kv_block, q8_map),
             pl.BlockSpec(kv_block, q8_map),
-            pl.BlockSpec((1, 1), scale_map, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), scale_map, memory_space=pltpu.SMEM),
+            pl.BlockSpec(sc_block, scale_map),
+            pl.BlockSpec(sc_block, scale_map),
         ],
         loader=q8_loader,
         out_width=dh, bs=page_size, s_steps=s_steps, scale=scale,
@@ -578,42 +609,25 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
     """
     b, one, h, dk = q.shape
     assert one == 1, q.shape
-    _, page_size, dk_c = c_pages.shape
+    n_pages, page_size, dk_c = c_pages.shape
     assert dk_c == dk, (q.shape, c_pages.shape)
     assert 0 < r < dk, (r, dk)
     assert cq_pages.shape == c_pages.shape and cq_pages.dtype == jnp.int8
-    assert c_scales.shape == c_pages.shape[:1] + (1,), c_scales.shape
+    assert c_scales.shape == (n_pages, 1), c_scales.shape
     assert pos.shape == (b,) and window.shape == (b,)
     assert block_tables.ndim == 2 and block_tables.shape[0] == b
     assert hot_window.shape == (1,)
     s_steps = block_tables.shape[1]
-    fp_map4, q8_map4, scale_map = _tier_maps(page_size)
-
-    # latent pools are rank-3: drop the degenerate head axis of the shared
-    # tier maps (h is always 0 on the MLA grid)
-    def c_fp_map(bb, g_, s, *sr):
-        p, _, _, _ = fp_map4(bb, 0, s, *sr)
-        return (p, 0, 0)
-
-    def c_q8_map(bb, g_, s, *sr):
-        p, _, _, _ = q8_map4(bb, 0, s, *sr)
-        return (p, 0, 0)
-
-    def cs_map(bb, g_, s, *sr):
-        return scale_map(bb, 0, s, *sr)
+    c_fp_map, c_q8_map, cs_map = _tier_maps(page_size, 3)
 
     def mla_q8_loader(scalars, t_refs, bb, s, pos_, win_):
         c_ref, cq_ref, cs_ref = t_refs
-        hw_ref = scalars[3]
-        first, last = _live_block_range(pos_, win_, page_size)
-        hot = jnp.clip(s, first, last) > last - hw_ref[0]
+        hot = _hot_now(scalars, s, pos_, win_, page_size)
 
         def load():
-            lat_fp = c_ref[0].astype(jnp.float32)      # (bs, r + d_rope)
-            lat_q8 = (cq_ref[0].astype(jnp.float32) * cs_ref[0, 0]) \
-                .astype(c_ref.dtype).astype(jnp.float32)
-            lat = jnp.where(hot, lat_fp, lat_q8)
-            return lat, lat[:, :r]
+            lat = jnp.where(hot, c_ref[...].astype(jnp.float32),
+                            _dequant(cq_ref[...], cs_ref[...], c_ref))
+            return lat, lat[:, :, :r]           # (1, bs, r + d_rope)
         return load
 
     return _flash_core(
@@ -621,11 +635,12 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
         scalars=(pos.astype(jnp.int32), window.astype(jnp.int32),
                  block_tables.astype(jnp.int32),
                  hot_window.astype(jnp.int32)),
-        tensors=(c_pages, cq_pages, c_scales.astype(jnp.float32)),
+        tensors=(c_pages, cq_pages,
+                 c_scales.astype(jnp.float32).reshape(n_pages, 1, 1)),
         tensor_specs=[
             pl.BlockSpec((1, page_size, dk), c_fp_map),
             pl.BlockSpec((1, page_size, dk), c_q8_map),
-            pl.BlockSpec((1, 1), cs_map, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 1), cs_map),
         ],
         loader=mla_q8_loader,
         out_width=r, bs=page_size, s_steps=s_steps, scale=scale,
@@ -655,6 +670,16 @@ def _pick_bs(s_max: int, bs: int) -> int:
             return tile
         tile //= 2
     return 128                      # pad < 128 <= limit always holds here
+
+
+def _vmem_bs_cap(hkv: int, dh: int) -> int:
+    """Largest power-of-two key-tile length whose (bs, Hkv, dh) K/V tile
+    stays within ``TILE_ELEMS``: every head of a tile is fetched at once,
+    so wide-head caches take shorter tiles to fit the scoped VMEM."""
+    cap = 8
+    while cap * 2 * hkv * dh <= TILE_ELEMS:
+        cap *= 2
+    return cap
 
 
 def _norm_scalar_vec(x, b: int, fill=None) -> jnp.ndarray:
@@ -700,7 +725,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qg = q.reshape(b, hkv, g, dh)      # same (hkv, g) grouping as _sdpa
     pos = _norm_scalar_vec(pos, b)
     win = _norm_scalar_vec(window, b, fill=s_max + 1)
-    bs_eff = _pick_bs(s_max, bs)
+    bs_eff = _pick_bs(s_max, min(bs, _vmem_bs_cap(hkv, dh)))
     pad = (-s_max) % bs_eff
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
@@ -87,7 +86,7 @@ def yoco_vmm_int8(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],       # the "time domain"
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'),
         ),
         interpret=interpret,
@@ -132,7 +131,7 @@ def int8_matmul(xq: jnp.ndarray, wq: jnp.ndarray, *, bm: int = DEFAULT_BM,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'),
         ),
         interpret=interpret,

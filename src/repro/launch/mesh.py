@@ -5,6 +5,7 @@ XLA flag, and only before its first jax import."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,11 +17,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     (overlapped with backward by XLA's latency-hiding scheduler)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_model: int = 1):
     """Whatever this host has — smoke tests and examples."""
     n = jax.device_count()
     assert n % n_model == 0
-    return jax.make_mesh((n // n_model, n_model), ("data", "model"))
+    return jax.make_mesh((n // n_model, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

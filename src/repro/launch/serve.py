@@ -47,8 +47,9 @@ Robustness (continuous mode): ``--deadline`` / ``--retry-budget`` /
 ``--max-queue`` bound each request's life (terminal fail/reject events
 instead of livelock or unbounded queues); every step a jit'd NaN/Inf
 sentinel on the logits quarantines poisoned lanes (pages scrubbed, request
-requeued, rest of the batch keeps decoding), and a kernel-path failure
-degrades the stream to the layout's einsum oracle. ``--chaos`` runs the
+requeued, rest of the batch keeps decoding), and an injected kernel fault
+degrades the stream to the layout's einsum oracle (a real kernel error
+propagates). ``--chaos`` runs the
 whole stream under ``runtime/faults.py``'s deterministic fault injector;
 the serve report carries the structured event log either way.
 
@@ -77,6 +78,7 @@ from repro.core.yoco_linear import YocoConfig
 from repro.core import yoco_linear
 from repro.data import synthetic
 from repro.distributed import sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_mod
 from repro.models.model import ModelRuntime
 from repro.runtime import faults as faults_mod
@@ -85,6 +87,12 @@ from repro.runtime import kv_quant as kvq
 from repro.runtime import layouts as layouts_mod
 from repro.runtime import serve_step as SS
 from repro.runtime import telemetry as telemetry_mod
+
+
+def _on_tpu() -> bool:
+    """The int8 matmuls take the Pallas kernels on TPU (``yoco_linear``'s
+    ``use_pallas``) and the XLA int8 dot elsewhere."""
+    return jax.default_backend() == 'tpu'
 
 
 def _ragged_lens(batch: int, prompt_len: int) -> jnp.ndarray:
@@ -110,7 +118,7 @@ def serve(arch: str, *, smoke: bool = True, batch: int = 4,
         raise ValueError(f'--attn-impl flash covers GQA decode on the '
                          f'contiguous cache; {arch} uses {kind} layers '
                          f'({hint})')
-    yoco = YocoConfig(mode=mode)
+    yoco = YocoConfig(mode=mode, use_pallas=_on_tpu())
     rt = ModelRuntime(attn_impl=attn_impl)
     max_seq = prompt_len + gen_len
 
@@ -623,6 +631,7 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
                      metrics_out: Optional[str] = None,
                      trace: Optional[str] = None,
                      registry=None,
+                     oracle_check: bool = False,
                      quiet: bool = False) -> dict:
     """Serve a stream of heterogeneous-length requests end-to-end (admit,
     decode, evict, re-admit) under one jit'd decode step.
@@ -641,11 +650,16 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
     whose faults the loop applies at the scheduler edges; every step the
     jit'd ``logits_finite`` sentinel quarantines lanes with non-finite
     logits (pages scrubbed, request requeued — the rest of the batch
-    keeps decoding), and a kernel-path exception under
-    ``attn_impl='flash'`` degrades the stream to the layout's densify
-    einsum oracle with a logged ``degrade`` event instead of crashing.
+    keeps decoding), and an injected kernel fault
+    (``faults.InjectedKernelError``) under ``attn_impl='flash'`` degrades
+    the stream to the layout's densify einsum oracle with a logged
+    ``degrade`` event; any other kernel error propagates, so a kernel
+    that fails on the device never hides behind the oracle.
     ``step_hook(sched, kv, cache)`` runs after every absorbed step (chaos
-    tests audit allocator invariants through it).
+    tests audit allocator invariants through it). ``oracle_check=True``
+    also runs the first decode step on the einsum oracle and reports the
+    kernel step's logit error against it (max abs difference over max abs
+    oracle logit, live lanes) as ``out['oracle_logit_err']``.
 
     Observability (PR 8): ``metrics=True`` (the default) threads
     ``runtime.telemetry.ServeTelemetry`` through the loop — request-span
@@ -701,7 +715,7 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
                 f'(CPU: set XLA_FLAGS=--xla_force_host_platform_'
                 f'device_count={tp} before importing jax)')
         mesh = jax.sharding.Mesh(np.asarray(devs[:tp]), ('model',))
-    yoco = YocoConfig(mode=mode)
+    yoco = YocoConfig(mode=mode, use_pallas=_on_tpu())
     rt = ModelRuntime(attn_impl=attn_impl)
     max_seq = prompt_len + gen_len
     max_blocks = -(-max_seq // page_size)
@@ -907,7 +921,7 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
                                jnp.asarray(stop, jnp.int32))
         return logits, part
 
-    def build_decode(impl):
+    def build_decode(impl, donate=(3,)):
         if mesh is not None:
             # the flash->einsum degrade path rebuilds THROUGH this too:
             # a TP stream degrades to the TP einsum oracle, never back to
@@ -917,16 +931,20 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
                                        attn_impl=impl, greedy=greedy,
                                        temperature=temperature,
                                        top_k=top_k),
-                donate_argnums=(3,))
+                donate_argnums=donate)
         return jax.jit(
             SS.make_decode_step(cfg, yoco, ModelRuntime(attn_impl=impl),
                                 greedy=greedy, temperature=temperature,
                                 top_k=top_k),
-            donate_argnums=(3,))
+            donate_argnums=donate)
 
     attn_impl_live = attn_impl
     decode_fn = build_decode(attn_impl_live)
     _decode_fns = [decode_fn]    # degrade rebuilds append here
+    # oracle check: the first decode step also runs on the einsum oracle
+    # (no donation, so the kernel step still reads the same cache)
+    oracle_fn = build_decode('einsum', donate=()) if oracle_check else None
+    oracle_err = None
     sentinel_fn = jax.jit(SS.logits_health)
     sample_key = jax.random.key(seed + 1)
 
@@ -1083,6 +1101,13 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
         cache = kvc.with_block_tables(cache, kv.table_array())
         busy_slot_steps += len(sched.active)
         active_now = sorted(sched.active)
+        if oracle_check and oracle_err is None and sched.active:
+            # the same step on the layout's einsum oracle, before the
+            # donating kernel step consumes the cache it reads
+            # (a sampled step's logits do not depend on its key)
+            ref_logits = oracle_fn(params, jnp.asarray(toks),
+                                   jnp.asarray(pos), cache,
+                                   *(() if greedy else (sample_key,)))[1]
         td0 = time.perf_counter()
         try:
             if (injector is not None and attn_impl_live == 'flash'
@@ -1091,13 +1116,12 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
                     'chaos: simulated kernel-path validation failure')
             tok, logits, cache = call_decode(cache, jnp.asarray(toks),
                                              jnp.asarray(pos))
-        except Exception as e:                  # noqa: BLE001 — any kernel-
-            # path failure degrades; re-raised when already on the oracle
-            if attn_impl_live != 'flash':
-                raise
-            # graceful degradation: trace/compile-time failures don't
-            # consume donated buffers, so the cache is intact — rebuild
-            # the step on the layout's densify einsum oracle and retry
+        except faults_mod.InjectedKernelError as e:
+            # graceful degradation, exercised by the chaos layer only: a
+            # real kernel failure propagates instead of hiding the device.
+            # The injected failure fires before the call, so the cache is
+            # intact — rebuild the step on the layout's densify einsum
+            # oracle and retry
             events.emit('degrade', step=steps, frm='flash', to='einsum',
                         error=f'{type(e).__name__}: {str(e)[:160]}')
             attn_impl_live = 'einsum'
@@ -1109,6 +1133,12 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
                             frm='flash', to='einsum')
             tok, logits, cache = call_decode(cache, jnp.asarray(toks),
                                              jnp.asarray(pos))
+        if oracle_check and oracle_err is None and sched.active:
+            lanes = jnp.asarray(active_now)
+            got = logits[lanes].astype(jnp.float32)
+            want = ref_logits[lanes].astype(jnp.float32)
+            oracle_err = float(jnp.max(jnp.abs(got - want))
+                               / jnp.maximum(jnp.max(jnp.abs(want)), 1e-30))
         if telem is not None:
             telem.decode(td0, time.perf_counter(), active_now)
         # --- integrity sentinel: quarantine non-finite lanes -------------
@@ -1196,6 +1226,7 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
         # (a degrade rebuild adds exactly one more, on the einsum oracle)
         decode_compilations=(sum(f._cache_size() for f in _decode_fns)
                             if hasattr(decode_fn, '_cache_size') else None),
+        oracle_logit_err=oracle_err,
         out_lens={r: len(t) for r, t in outputs.items()},
         sample={r: t[:4] for r, t in list(outputs.items())[:4]},
     )
@@ -1226,7 +1257,10 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch', default='stablelm-1.6b')
-    ap.add_argument('--smoke', action='store_true', default=True)
+    ap.add_argument('--smoke', action='store_true', default=True,
+                    help='reduced-width config (the default)')
+    ap.add_argument('--full', dest='smoke', action='store_false',
+                    help="the arch's published widths")
     ap.add_argument('--batch', type=int, default=4)
     ap.add_argument('--prompt-len', type=int, default=32)
     ap.add_argument('--gen-len', type=int, default=32)
@@ -1307,6 +1341,7 @@ def main(argv=None):
                     help='write a Chrome-trace/Perfetto JSON of the run '
                          '(load at ui.perfetto.dev)')
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.continuous:
         injector = (faults_mod.FaultInjector(
             seed=args.chaos_seed, profile=faults_mod.chaos_profile())

@@ -27,6 +27,7 @@ from repro import configs
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.core.yoco_linear import YocoConfig
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as model_mod
 from repro.models.model import ModelRuntime
@@ -130,6 +131,7 @@ def main(argv=None):
                     choices=['bf16', 'qat', 'w8a8', 'analog_sim'])
     ap.add_argument('--simulate-failure-at', type=int, default=-1)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = train(args.arch, smoke=args.smoke, steps=args.steps,
                 global_batch=args.global_batch, seq_len=args.seq_len,
                 lr=args.lr, grad_accum=args.grad_accum,

@@ -35,7 +35,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import yoco_linear
 from repro.core.yoco_linear import YocoConfig
 from repro.models import rope as rope_mod
@@ -532,7 +531,7 @@ def _mla_sdpa_latent_2d(q_nope, q_rope, ckv, krope, w_ukv, cfg, rt, s):
         return out.astype(qn.dtype)
 
     dp = rt.dp_axes
-    return compat.shard_map(
+    return jax.shard_map(
         core, mesh=rt.mesh,
         in_specs=(P(dp, tp, None, None), P(dp, tp, None, None),
                   P(dp, tp, None), P(dp, tp, None), P()),

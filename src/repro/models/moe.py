@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import yoco_linear
 from repro.core.yoco_linear import YocoConfig
 from repro.models.layers import dense_init
@@ -264,7 +263,7 @@ def moe_ep(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, ctx: EPContext,
     xspec = (P(ctx.dp_axes, ep_axis, None) if seq_sharded
              else P(ctx.dp_axes, None, None))
 
-    y, metrics = compat.shard_map(
+    y, metrics = jax.shard_map(
         shard_fn, mesh=ctx.mesh,
         in_specs=(pspecs, xspec),
         out_specs=(xspec, P()),

@@ -50,10 +50,10 @@ import numpy as np
 
 
 class InjectedKernelError(RuntimeError):
-    """A simulated kernel-path validation failure (chaos only). The serve
-    loop's degrade handler treats it like any other kernel-path exception:
-    fall back to the layout's densify einsum oracle and log a ``degrade``
-    event."""
+    """A simulated kernel-path validation failure (chaos only). It is the
+    one exception the serve loop's degrade handler catches: fall back to
+    the layout's densify einsum oracle and log a ``degrade`` event. Real
+    kernel errors propagate."""
 
 
 # ----------------------------------------------------------------------------

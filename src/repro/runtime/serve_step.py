@@ -129,9 +129,8 @@ def serve_tp_specs(params, cache, tp_axis: str = 'model'):
 
 
 def _tp_wrap(body, mesh, in_specs, out_specs):
-    from repro import compat
-    return compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_tp_prefill_step(cfg, yoco: YocoConfig, mesh, params, cache, *,

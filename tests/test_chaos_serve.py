@@ -140,6 +140,19 @@ def test_kernel_fault_degrades_to_einsum_with_parity():
                    KW['gen_len'])
 
 
+def test_real_kernel_error_propagates(monkeypatch):
+    """Only the chaos layer's injected fault degrades: a real exception
+    on the kernel path propagates instead of hiding behind the oracle."""
+    from repro.runtime import layouts
+
+    def broken(cls, *a, **k):
+        raise RuntimeError('kernel failed to compile')
+    monkeypatch.setattr(layouts.PagedLayout, 'flash_decode',
+                        classmethod(broken))
+    with pytest.raises(RuntimeError, match='kernel failed to compile'):
+        SV.serve_continuous(ARCH, attn_impl='flash', **KW)
+
+
 def test_pool_squeeze_and_storm_recover_with_parity():
     """Held-hostage pages + forced preemption storms: pure recompute
     churn, so every request that completes is token-identical."""

@@ -1,10 +1,9 @@
-"""Compat-shim + API-drift canary tests.
+"""API-drift canary tests.
 
-The repo pins JAX (0.4.37 today); every version-sensitive JAX API routes
-through ``repro.compat``. These tests import every module under
-``src/repro`` and run a tiny forward in each of the four YocoConfig modes,
-so the next JAX API drift fails loudly at import/smoke level instead of
-deep inside a parametrized kernel test.
+The code is written for the installed JAX (0.9.0). These tests import
+every module under ``src/repro`` and run a tiny forward in each of the
+four YocoConfig modes, so the next JAX API drift fails loudly at
+import/smoke level instead of deep inside a parametrized kernel test.
 """
 
 import importlib
@@ -12,11 +11,9 @@ import pkgutil
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 import repro
-from repro import compat
 
 
 def _all_repro_modules():
@@ -29,21 +26,6 @@ def _all_repro_modules():
 @pytest.mark.parametrize('mod', _all_repro_modules())
 def test_every_module_imports(mod):
     importlib.import_module(mod)
-
-
-def test_tpu_compiler_params_resolves():
-    cp = compat.tpu_compiler_params(
-        dimension_semantics=('parallel', 'arbitrary'))
-    assert cp.dimension_semantics == ('parallel', 'arbitrary')
-
-
-def test_shard_map_shim_runs_on_degenerate_mesh():
-    mesh = jax.make_mesh((1,), ('d',))
-    P = jax.sharding.PartitionSpec
-    x = jnp.arange(8.0)
-    y = compat.shard_map(lambda a: a * 2.0, mesh=mesh, in_specs=(P(),),
-                        out_specs=P(), check_vma=False)(x)
-    np.testing.assert_allclose(np.asarray(y), np.arange(8.0) * 2.0)
 
 
 @pytest.mark.parametrize('mode', ['bf16', 'qat', 'w8a8', 'analog_sim'])

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.distributed import collectives, sharding
 
 pytestmark = pytest.mark.distributed
@@ -29,8 +28,8 @@ def _mesh(n, axis='data'):
 @pytest.mark.parametrize('n', [2, 4])
 def test_psum_mean_matches_numpy(n):
     mesh = _mesh(n)
-    f = compat.shard_map(lambda x: collectives.psum_mean(x, 'data'),
-                        mesh=mesh, in_specs=P('data'), out_specs=P())
+    f = jax.shard_map(lambda x: collectives.psum_mean(x, 'data'),
+                      mesh=mesh, in_specs=P('data'), out_specs=P())
     x = jnp.arange(4.0 * n).reshape(n * 2, 2)
     got = np.asarray(jax.jit(f)(x))
     want = np.asarray(x).reshape(n, 2, 2).mean(axis=0)
@@ -41,7 +40,7 @@ def test_all_gather_tiled_reassembles_exactly():
     # the serving TP collective: a tiled all-gather on the last dim must
     # reassemble the original array bit-for-bit (head-major concat)
     mesh = _mesh(4)
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda x: jax.lax.all_gather(x, 'data', axis=x.ndim - 1,
                                      tiled=True),
         mesh=mesh, in_specs=P(None, 'data'), out_specs=P(None, None),
@@ -56,7 +55,7 @@ def test_all_gather_tiled_reassembles_exactly():
 @pytest.mark.parametrize('n', [2, 4])
 def test_compressed_psum_close_to_exact_mean(n):
     mesh = _mesh(n)
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda x, e: collectives.compressed_psum(x, 'data', e),
         mesh=mesh, in_specs=(P('data'), P('data')),
         out_specs=(P(), P('data')), check_vma=False)
@@ -78,7 +77,7 @@ def test_compressed_psum_error_feedback_compensates():
     over many steps converges to the true value, instead of losing the
     bias to quantization forever."""
     mesh = _mesh(2)
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x, e: collectives.compressed_psum(x, 'data', e),
         mesh=mesh, in_specs=(P('data'), P('data')),
         out_specs=(P(), P('data')), check_vma=False))
@@ -106,7 +105,7 @@ def test_compressed_psum_int8_on_the_wire():
     """The wire contract: what crosses the psum is an int32 sum of int8
     payloads, not the f32 tensor — pinned by inspecting the jaxpr."""
     mesh = _mesh(2)
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda x, e: collectives.compressed_psum(x, 'data', e),
         mesh=mesh, in_specs=(P('data'), P('data')),
         out_specs=(P(), P('data')), check_vma=False)
@@ -121,7 +120,7 @@ def test_tree_compressed_psum_structure():
     mesh = _mesh(2)
     tree = dict(a=jnp.ones((2, 2)), b=dict(c=jnp.full((2, 4), 2.0)))
     ef = jax.tree.map(jnp.zeros_like, tree)
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda t, e: collectives.tree_compressed_psum(t, 'data', e),
         mesh=mesh, in_specs=(P('data'), P('data')),
         out_specs=(P(), P('data')), check_vma=False)

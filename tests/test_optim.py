@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.optim import adamw
 from repro.distributed import collectives
 
@@ -68,7 +67,7 @@ def test_compressed_psum_on_single_device_mesh():
     def f(x, ef):
         return collectives.compressed_psum(x, 'd', ef)
 
-    mean, new_ef = compat.shard_map(
+    mean, new_ef = jax.shard_map(
         f, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
         out_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),

@@ -211,3 +211,20 @@ def test_decode_step_sampled_signature_and_determinism():
     topk_ids = np.asarray(jax.lax.top_k(logits, 8)[1])
     for b in range(2):
         assert int(t1[b]) in topk_ids[b].tolist()
+
+
+@pytest.mark.parametrize('mode,prequantize,kv_quant', [
+    ('bf16', False, False), ('bf16', False, True), ('w8a8', True, False)])
+def test_oracle_check_reports_small_logit_error(mode, prequantize, kv_quant):
+    """``oracle_check`` runs the first decode step on the einsum oracle as
+    well: the flash step's logits agree within the bounds chip_smoke.py
+    holds the chip to, and the oracle adds no decode compilation."""
+    out = SV.serve_continuous(ARCH, slots=2, n_requests=3, prompt_len=16,
+                              gen_len=4, page_size=4, mode=mode,
+                              prequantize=prequantize, kv_quant=kv_quant,
+                              hot_window=1, attn_impl='flash',
+                              oracle_check=True, metrics=False, quiet=True)
+    assert out['completed'] == 3
+    assert out['decode_compilations'] == 1
+    assert 0.0 <= out['oracle_logit_err'] <= (0.15 if mode == 'w8a8'
+                                              else 0.05)

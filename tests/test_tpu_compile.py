@@ -1,0 +1,247 @@
+"""Compile-only checks of every Pallas kernel entrypoint for a described
+TPU v5e, at the widths the serving path runs them: stablelm-1.6b
+(Hkv = 32, dh = 64, and its Hkv = 8 shard under 4-way TP) and the
+deepseek-v3 latent pool (r + d_rope = 576, 128 heads).
+
+Interpret mode (every other kernel test) cannot see Mosaic's tiling and
+VMEM rules; the TPU compiler that ships with jaxlib can, without a chip.
+The topology is described inside a module fixture — never at import time
+— so every test worker collects the same tests and only the one that runs
+this file loads the TPU library."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import flash_decode as fd
+from repro.kernels import ops
+from repro.kernels import quantize, yoco_vmm
+
+BF, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+B, PS, W = 4, 16, 10            # slots, page size, block-table width
+P = 1 + B * W                   # pool pages incl. the garbage page
+DH = 64                         # stablelm-1.6b head dim
+R, DR, HM = 512, 64, 128        # deepseek-v3 latent rank, rope dim, heads
+
+
+@pytest.fixture(scope='module')
+def topo():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:      # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+
+
+@pytest.fixture(scope='module')
+def compile_tpu(topo):
+    """``compile_tpu(fn, *(shape, dtype))`` -> compiled HLO text of ``fn``
+    for one described v5e chip (shapes only; nothing runs). The persistent
+    compilation cache stays off: a described-chip compile cannot be read
+    back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update('jax_enable_compilation_cache', was)
+
+
+def _kv(hkv, dtype=BF):
+    return ((P, PS, hkv, DH), dtype)
+
+
+@pytest.mark.parametrize('hkv', [32, 8])
+def test_flash_decode_contiguous(compile_tpu, hkv):
+    txt = compile_tpu(
+        lambda q, k, v, p: fd.flash_decode(q, k, v, p, scale=0.125,
+                                           interpret=False),
+        ((B, 1, 32, DH), BF), ((B, 4096, hkv, DH), BF),
+        ((B, 4096, hkv, DH), BF), ((B,), I32))
+    assert 'tpu_custom_call' in txt
+
+
+@pytest.mark.parametrize('hkv', [32, 8])
+def test_flash_decode_paged(compile_tpu, hkv):
+    txt = compile_tpu(
+        lambda q, k, v, p, bt: fd.flash_decode_paged(
+            q, k, v, p, bt, scale=0.125, interpret=False),
+        ((B, 1, hkv, DH), BF), _kv(hkv), _kv(hkv), ((B,), I32),
+        ((B, W), I32))
+    assert 'tpu_custom_call' in txt
+
+
+@pytest.mark.parametrize('hkv', [32, 8])
+def test_flash_decode_paged_q8(compile_tpu, hkv):
+    txt = compile_tpu(
+        lambda q, k, v, kq, vq, ks, vs, p, bt, hw: fd.flash_decode_paged_q8(
+            q, k, v, kq, vq, ks, vs, p, bt, hw, scale=0.125,
+            interpret=False),
+        ((B, 1, hkv, DH), BF), _kv(hkv), _kv(hkv), _kv(hkv, I8),
+        _kv(hkv, I8), ((P, hkv), F32), ((P, hkv), F32), ((B,), I32),
+        ((B, W), I32), ((1,), I32))
+    assert 'tpu_custom_call' in txt
+
+
+@pytest.mark.parametrize('hkv', [32, 8])
+def test_flash_chunk_paged(compile_tpu, hkv):
+    txt = compile_tpu(
+        lambda q, k, v, o, lim, bt: fd.flash_chunk_paged(
+            q, k, v, o, lim, bt, scale=0.125, interpret=False),
+        ((1, PS, hkv, DH), BF), _kv(hkv), _kv(hkv), ((1,), I32),
+        ((1,), I32), ((1, W), I32))
+    assert 'tpu_custom_call' in txt
+
+
+def test_flash_decode_paged_mla(compile_tpu):
+    txt = compile_tpu(
+        lambda q, c, p, bt: fd.flash_decode_paged_mla(
+            q, c, p, bt, r=R, scale=0.1, interpret=False),
+        ((B, 1, HM, R + DR), BF), ((P, PS, R + DR), BF), ((B,), I32),
+        ((B, W), I32))
+    assert 'tpu_custom_call' in txt
+
+
+def test_flash_decode_paged_mla_q8(compile_tpu):
+    txt = compile_tpu(
+        lambda q, c, cq, cs, p, bt, hw: fd.flash_decode_paged_mla_q8(
+            q, c, cq, cs, p, bt, hw, r=R, scale=0.1, interpret=False),
+        ((B, 1, HM, R + DR), BF), ((P, PS, R + DR), BF),
+        ((P, PS, R + DR), I8), ((P, 1), F32), ((B,), I32), ((B, W), I32),
+        ((1,), I32))
+    assert 'tpu_custom_call' in txt
+
+
+def test_flash_chunk_paged_mla(compile_tpu):
+    txt = compile_tpu(
+        lambda q, c, o, lim, bt: fd.flash_chunk_paged_mla(
+            q, c, o, lim, bt, r=R, scale=0.1, interpret=False),
+        ((1, PS, HM, R + DR), BF), ((P, PS, R + DR), BF), ((1,), I32),
+        ((1,), I32), ((1, W), I32))
+    assert 'tpu_custom_call' in txt
+
+
+@pytest.mark.parametrize('k,n', [(2048, 5632), (5632, 2048)])
+def test_int8_matmul(compile_tpu, k, n):
+    txt = compile_tpu(
+        lambda x, w: yoco_vmm.int8_matmul(x, w, interpret=False),
+        ((128, k), I8), ((k, n), I8))
+    assert 'tpu_custom_call' in txt
+
+
+def test_yoco_vmm_int8(compile_tpu):
+    txt = compile_tpu(
+        lambda x, w, sx, sw: yoco_vmm.yoco_vmm_int8(x, w, sx, sw,
+                                                    interpret=False),
+        ((128, 2048), I8), ((2048, 5632), I8), ((128, 1), F32),
+        ((1, 5632), F32))
+    assert 'tpu_custom_call' in txt
+
+
+def test_quantize_rows(compile_tpu):
+    txt = compile_tpu(
+        lambda x: quantize.quantize_rows(x, bm=128, interpret=False),
+        ((128, 2048), BF))
+    assert 'tpu_custom_call' in txt
+
+
+@pytest.mark.parametrize('m', [4, 128])
+@pytest.mark.parametrize('k,n', [(2048, 2048), (2048, 100352)])
+def test_ops_int8_matmul_serving_shapes(compile_tpu, monkeypatch, m, k, n):
+    """The padded wrapper the w8a8 serving path calls, at a decode batch
+    (m = 4 slots) and a prefill slab (m = 128 prompt tokens), for the
+    attention projection and the lm_head."""
+    monkeypatch.setattr(ops, '_interpret', lambda: False)
+    txt = compile_tpu(ops.int8_matmul, ((m, k), I8), ((k, n), I8))
+    assert 'tpu_custom_call' in txt
+
+
+@pytest.mark.parametrize('mode,kv_quant', [('bf16', False), ('bf16', True),
+                                           ('w8a8', False)])
+def test_stablelm_decode_step_full_width(compile_tpu, monkeypatch, mode,
+                                         kv_quant):
+    """The whole continuous decode step of stablelm-1.6b at its published
+    widths (24 layers, 4 slots, 16-token pages, 160 positions), built the
+    way ``serve_continuous`` builds it on a TPU: flash attention over the
+    paged pool and, for w8a8, prequantized int8 weights through the Pallas
+    matmul. The scanned layer body carries the attention kernel (plus the
+    int8 matmuls under w8a8)."""
+    from repro import configs
+    from repro.core import yoco_linear
+    from repro.core.yoco_linear import YocoConfig
+    from repro.models import model as model_mod
+    from repro.models.model import ModelRuntime
+    from repro.runtime import serve_step as SS
+
+    monkeypatch.setattr(ops, '_interpret', lambda: False)
+    cfg = configs.get('stablelm-1.6b', smoke=False)
+    slots, ps, max_blocks = 4, 16, 10
+
+    def make_params(key):
+        p = model_mod.init_params(key, cfg)
+        return yoco_linear.quantize_tree(p) if mode == 'w8a8' else p
+
+    params = jax.eval_shape(make_params, jax.random.key(0))
+    cache = jax.eval_shape(lambda: model_mod.init_paged_cache_tree(
+        cfg, slots, num_pages=1 + slots * max_blocks, page_size=ps,
+        max_blocks=max_blocks, kv_dtype='int8' if kv_quant else None,
+        hot_window=2))
+    step = SS.make_decode_step(cfg, YocoConfig(mode=mode, use_pallas=True),
+                               ModelRuntime(attn_impl='flash'))
+    specs = [(a.shape, a.dtype) for a in jax.tree.leaves((params, cache))]
+    treedef = jax.tree.structure((params, cache))
+
+    def flat_step(tok, pos, *leaves):
+        p, c = jax.tree.unflatten(treedef, leaves)
+        return step(p, tok, pos, c)
+
+    txt = compile_tpu(flat_step, ((slots,), I32), ((slots,), I32), *specs)
+    assert txt.count('tpu_custom_call') >= (2 if mode == 'w8a8' else 1)
+
+
+def test_stablelm_tp4_decode_step_full_width(topo, compile_tpu, monkeypatch):
+    """The ``--tp 4`` decode step of stablelm-1.6b over a mesh of the four
+    described chips: the flash kernel runs inside ``shard_map`` on each
+    chip's 8 KV heads, and the per-head outputs are all-gathered before
+    the replicated ``wo``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro import configs
+    from repro.core.yoco_linear import YocoConfig
+    from repro.models import model as model_mod
+    from repro.runtime import serve_step as SS
+
+    monkeypatch.setattr(ops, '_interpret', lambda: False)
+    cfg = configs.get('stablelm-1.6b', smoke=False)
+    mesh = Mesh(np.asarray(topo.devices[:4]), ('model',))
+    slots, ps, max_blocks = 4, 16, 10
+    params = jax.eval_shape(lambda: model_mod.init_params(jax.random.key(0),
+                                                          cfg))
+    cache = jax.eval_shape(lambda: model_mod.init_paged_cache_tree(
+        cfg, slots, num_pages=1 + slots * max_blocks, page_size=ps,
+        max_blocks=max_blocks))
+    pspecs, cspecs = SS.serve_tp_specs(params, cache)
+
+    def placed(tree, specs):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, s)), tree, specs)
+
+    vec = jax.ShapeDtypeStruct((slots,), I32,
+                               sharding=NamedSharding(mesh, PartitionSpec()))
+    step = SS.make_tp_decode_step(cfg, YocoConfig(mode='bf16', use_pallas=True),
+                                  mesh, params, cache, attn_impl='flash')
+    txt = jax.jit(step).lower(placed(params, pspecs), vec, vec,
+                              placed(cache, cspecs)).compile().as_text()
+    assert 'tpu_custom_call' in txt and 'all-gather' in txt
