@@ -670,10 +670,12 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
     writes the snapshot to a file (``.prom`` suffix: Prometheus text
     exposition, else JSON). ``trace`` writes a Chrome-trace/Perfetto
     JSON of the run (one track per slot plus a scheduler track; loads in
-    ui.perfetto.dev). ``metrics=False`` skips all instrumentation — the
-    benchmarked overhead gate compares the two. Either way the report's
-    terminal counts are derived from ``EventLog.terminal_accounting()``
-    itself (single source of truth), not parallel counters."""
+    ui.perfetto.dev). Every loop phase is a ``serve.*`` span
+    (``telemetry.Span``): a profiler annotation even with ``metrics=False``
+    (about 1 us), which skips the rest — the overhead gate compares the
+    two; the snapshot's ``slowest_steps`` holds the longest steps by
+    phase. Terminal counts derive from ``EventLog.terminal_accounting()``
+    (single source of truth), not parallel counters."""
     cfg = configs.get(arch, smoke=smoke)
     # routing table (pinned by tests/test_serve_continuous.py): every token
     # family serves — MLA pages its latent pool through the same block
@@ -966,6 +968,8 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
     steps = busy_slot_steps = 0
     peak_pages = 0
     t_prefill = 0.0
+    # each loop phase is a profiler annotation, and telemetry when on
+    span = telem.span if telem is not None else telemetry_mod.span
 
     def _admit_one(req, slot, plan):
         """One admission, every layout: zero the slot's recurrent rows (a
@@ -978,199 +982,195 @@ def serve_continuous(arch: str, *, smoke: bool = True, slots: int = 4,
         zeroing); sealing right after prefill lets the NEXT admission of
         this same step share the pages just published."""
         nonlocal cache, t_prefill
-        tp = time.perf_counter()
-        cache = layouts_mod.reset_state_slots(cache, [slot])
-        part = layouts_mod.slice_state_slot(
-            kvc.with_block_tables(cache, kv.tables[slot:slot + 1]), slot)
-        logits, part = run_prefill(part, req, slot, plan)
-        cache = layouts_mod.merge_state_slot(cache, part, slot)
-        kv.seal_slot(slot, req.prompt)
-        tp_end = time.perf_counter()
-        t_prefill += tp_end - tp
+        with span('serve.prefill') as sp:
+            cache = layouts_mod.reset_state_slots(cache, [slot])
+            part = layouts_mod.slice_state_slot(
+                kvc.with_block_tables(cache, kv.tables[slot:slot + 1]),
+                slot)
+            logits, part = run_prefill(part, req, slot, plan)
+            cache = layouts_mod.merge_state_slot(cache, part, slot)
+            kv.seal_slot(slot, req.prompt)
+        t_prefill += sp.seconds
         # the admit event predates the prefill; attach the measured
         # duration so spans (TTFT) derive from the log alone
-        events.annotate_last('admit', req.rid, prefill_s=tp_end - tp)
-        if telem is not None:
-            telem.prefill(rid=req.rid, slot=slot, t_start=tp, t_end=tp_end)
-        sched.seed(req, slot, first_token(logits))
+        events.annotate_last('admit', req.rid, prefill_s=sp.seconds)
+        with span('serve.wait', on='prefill'):
+            tok0 = first_token(logits)
+        sched.seed(req, slot, tok0)
 
     t0 = time.time()
     limit = max_steps if max_steps is not None else \
         n_requests * (prompt_len + gen_len) * 4 + 64
     while not sched.done and steps < limit:
-        t_step0 = time.perf_counter()
-        sched.begin_step(steps)
-        if telem is not None:
-            telem.begin_step(steps, t_step0)
-        if injector is not None:
-            injector.begin_step(steps)
-            # pool squeeze: the injector holds free pages hostage; the
-            # scheduler sees a smaller pool and must queue/preempt
-            want = injector.squeeze_pages()
-            delta = want - len(kv.reserved)
-            if delta > 0:
-                if kv.reserve_pages(delta):
-                    events.emit('fault', step=steps, fault='pool_squeeze',
-                                held=len(kv.reserved))
-            elif delta < 0:
-                kv.unreserve_pages(-delta)
-            # mid-stream cancellation of a live (pending or active) rid
-            want_cancel = injector.cancel_now()
-            if want_cancel:
-                live = sorted({st.req.rid for st in sched.active.values()}
-                              | {r.rid for r in sched.pending})
-                rid = want_cancel if not isinstance(want_cancel, bool) \
-                    else (injector.pick(live) if live else None)
-                if rid is not None:
-                    sched.cancel(rid)
-        # --- admit on release -------------------------------------------
-        if has_pool:
+        with span('serve.step', step=steps):
+            sched.begin_step(steps)
+            if injector is not None:
+                injector.begin_step(steps)
+                # pool squeeze: the injector holds free pages hostage;
+                # the scheduler sees a smaller pool and must queue/preempt
+                want = injector.squeeze_pages()
+                delta = want - len(kv.reserved)
+                if delta > 0:
+                    if kv.reserve_pages(delta):
+                        events.emit('fault', step=steps,
+                                    fault='pool_squeeze',
+                                    held=len(kv.reserved))
+                elif delta < 0:
+                    kv.unreserve_pages(-delta)
+                # mid-stream cancellation of a live (pending or active) rid
+                want_cancel = injector.cancel_now()
+                if want_cancel:
+                    live = sorted({st.req.rid for st in sched.active.values()}
+                                  | {r.rid for r in sched.pending})
+                    rid = want_cancel if not isinstance(want_cancel, bool) \
+                        else (injector.pick(live) if live else None)
+                    if rid is not None:
+                        sched.cancel(rid)
+            # --- admit on release -------------------------------------
             # deferred scrubs: pages a quarantined tenant shared with a
             # then-live lane reach the queue on that lane's own release —
             # zero them before the free list can hand them out again
-            deferred = kv.drain_scrub_queue()
+            deferred = kv.drain_scrub_queue() if has_pool else None
             if deferred:
-                cache = scrub_pages(cache, deferred)
-        # prefix caching admits one at a time (prefill + seal between
-        # admissions) so same-step bursts share the first tenant's pages
-        admit_limit = 1 if prefix_cache else None
-        while True:
-            batch = sched.try_admit(limit=admit_limit)
-            if not batch:
+                with span('serve.scrub', pages=len(deferred)):
+                    cache = scrub_pages(cache, deferred)
+            # prefix caching admits one at a time (prefill + seal between
+            # admissions) so same-step bursts share the first tenant's pages
+            admit_limit = 1 if prefix_cache else None
+            while True:
+                batch = sched.try_admit(limit=admit_limit)
+                if not batch:
+                    break
+                for req, slot, plan in batch:
+                    with span('serve.admit', rid=req.rid, slot=slot,
+                              plen=len(req.prompt)):
+                        _admit_one(req, slot, plan)
+                if admit_limit is None:
+                    break
+            if sched.done:
                 break
-            for req, slot, plan in batch:
-                _admit_one(req, slot, plan)
-            if admit_limit is None:
-                break
-        if sched.done:
-            break
-        if injector is not None:
-            # preemption storm: force-preempt lanes (freshly admitted too)
-            for _ in range(injector.storm_count()):
-                if sched.force_preempt():
-                    events.emit('fault', step=steps,
-                                fault='preempt_storm')
-        # --- grow + decode one step over every lane ----------------------
-        sched.grow_for_decode()
-        if has_recurrent and sched.dirty_slots:
-            # evicted/preempted lanes decode against zeroed state until
-            # re-admission (constant step shapes, nothing recompiles)
-            cache = layouts_mod.reset_state_slots(
-                cache, sorted(set(sched.dirty_slots)))
-        sched.dirty_slots.clear()
-        if kv_quant:
-            # pages that just left the hot window become int8 before the
-            # step reads them as cold (covers fresh admissions too)
-            tq = time.perf_counter()
-            quantized_before = n_pages_quantized
-            cache = quantize_aged_out(cache)
-            if telem is not None and n_pages_quantized > quantized_before:
-                telem.phase('quantize', tq, time.perf_counter(),
-                            pages=n_pages_quantized - quantized_before)
-        if (injector is not None and has_pool and sched.active
-                and injector.poison_page_now()):
-            # NaN an owned fp pool page: the model of a corrupted
-            # in-memory read; the sentinel below must catch the lane
-            cand = [(s, int(p)) for s in sorted(sched.active)
-                    for p in kv.tables[s, :int(kv.counts[s])]]
-            if cand:
-                slot, page = injector.pick(cand)
-                cache = poison_page_op(cache, page)
-                # a shared page poisons EVERY owner: each one trips the
-                # sentinel below, and the first quarantine retires the
-                # page from the prefix table so no later admission can
-                # acquire the suspect content
-                owners = kv.owners_of(page) if prefix_cache else [slot]
-                events.emit('fault', step=steps, fault='poison_page',
-                            slot=slot, page=page,
-                            rid=sched.active[slot].req.rid,
-                            owners=owners)
-        poison_slot = None
-        if (injector is not None and sched.active
-                and injector.poison_logits_now()):
-            poison_slot = injector.pick(sorted(sched.active))
-            events.emit('fault', step=steps, fault='poison_logits',
-                        slot=poison_slot,
-                        rid=sched.active[poison_slot].req.rid)
-        peak_pages = max(peak_pages, kv.used_pages)
-        if telem is not None:
-            # gauges + hwmodel energy pricing over the step's actual
-            # batch composition (pos/tier state is final by here)
-            telem.sample(sched, kv)
-        toks, pos = sched.step_vectors()
-        cache = kvc.with_block_tables(cache, kv.table_array())
-        busy_slot_steps += len(sched.active)
-        active_now = sorted(sched.active)
-        if oracle_check and oracle_err is None and sched.active:
-            # the same step on the layout's einsum oracle, before the
-            # donating kernel step consumes the cache it reads
-            # (a sampled step's logits do not depend on its key)
-            ref_logits = oracle_fn(params, jnp.asarray(toks),
-                                   jnp.asarray(pos), cache,
-                                   *(() if greedy else (sample_key,)))[1]
-        td0 = time.perf_counter()
-        try:
-            if (injector is not None and attn_impl_live == 'flash'
-                    and injector.kernel_fault_now()):
-                raise faults_mod.InjectedKernelError(
-                    'chaos: simulated kernel-path validation failure')
-            tok, logits, cache = call_decode(cache, jnp.asarray(toks),
-                                             jnp.asarray(pos))
-        except faults_mod.InjectedKernelError as e:
-            # graceful degradation, exercised by the chaos layer only: a
-            # real kernel failure propagates instead of hiding the device.
-            # The injected failure fires before the call, so the cache is
-            # intact — rebuild the step on the layout's densify einsum
-            # oracle and retry
-            events.emit('degrade', step=steps, frm='flash', to='einsum',
-                        error=f'{type(e).__name__}: {str(e)[:160]}')
-            attn_impl_live = 'einsum'
-            tdg = time.perf_counter()
-            decode_fn = build_decode('einsum')
-            _decode_fns.append(decode_fn)
-            if telem is not None:
-                telem.phase('degrade', tdg, time.perf_counter(),
-                            frm='flash', to='einsum')
-            tok, logits, cache = call_decode(cache, jnp.asarray(toks),
-                                             jnp.asarray(pos))
-        if oracle_check and oracle_err is None and sched.active:
-            lanes = jnp.asarray(active_now)
-            got = logits[lanes].astype(jnp.float32)
-            want = ref_logits[lanes].astype(jnp.float32)
-            oracle_err = float(jnp.max(jnp.abs(got - want))
-                               / jnp.maximum(jnp.max(jnp.abs(want)), 1e-30))
-        if telem is not None:
-            telem.decode(td0, time.perf_counter(), active_now)
-        # --- integrity sentinel: quarantine non-finite lanes -------------
-        ok, logit_max = sentinel_fn(logits)
-        if poison_slot is not None:
-            lg = np.asarray(logits, np.float32)
-            lg[poison_slot] = np.nan
-            ok, logit_max = sentinel_fn(jnp.asarray(lg))
-        ok = np.asarray(ok)
-        if telem is not None:
-            # device scalar handed over as-is; telemetry host-transfers it
-            # once at finish, never per step
-            telem.logits_gauge(logit_max)
-        bad = [s for s in sorted(sched.active) if not ok[s]]
-        for slot in bad:
-            # quarantine BEFORE absorb: a poisoned lane must not finish
-            # on a garbage token (argmax over NaN logits is id 0). The
-            # requeue is lossless — recompute re-derives the state from
-            # the prompt — and the scrub keeps the poison from leaking
-            # to the page's next tenant.
-            tsb = time.perf_counter()
-            pages = sched.quarantine(slot)
-            cache = scrub_pages(cache, pages)
-            if telem is not None:
-                telem.phase('scrub', tsb, time.perf_counter(),
-                            slot=slot, pages=len(pages))
-        sched.absorb(np.asarray(tok))
-        steps += 1
-        if step_hook is not None:
-            step_hook(sched, kv, cache)
-        if telem is not None:
-            telem.step_done(time.perf_counter())
+            if injector is not None:
+                # preemption storm: force-preempt lanes (fresh ones too)
+                for _ in range(injector.storm_count()):
+                    if sched.force_preempt():
+                        events.emit('fault', step=steps,
+                                    fault='preempt_storm')
+            # --- grow + decode one step over every lane -----------------
+            with span('serve.dispatch') as dispatch:
+                sched.grow_for_decode()
+                if has_recurrent and sched.dirty_slots:
+                    # evicted/preempted lanes decode against zeroed state
+                    # until re-admission (constant shapes, no recompile)
+                    cache = layouts_mod.reset_state_slots(
+                        cache, sorted(set(sched.dirty_slots)))
+                sched.dirty_slots.clear()
+                if kv_quant:
+                    # pages that just left the hot window become int8
+                    # before the step reads them as cold (fresh ones too)
+                    with span('serve.quantize') as sq:
+                        quantized_before = n_pages_quantized
+                        cache = quantize_aged_out(cache)
+                        sq.note(pages=n_pages_quantized - quantized_before)
+                if (injector is not None and has_pool and sched.active
+                        and injector.poison_page_now()):
+                    # NaN an owned fp pool page: the model of a corrupted
+                    # in-memory read; the sentinel below must catch it
+                    cand = [(s, int(p)) for s in sorted(sched.active)
+                            for p in kv.tables[s, :int(kv.counts[s])]]
+                    if cand:
+                        slot, page = injector.pick(cand)
+                        cache = poison_page_op(cache, page)
+                        # a shared page poisons EVERY owner: each trips
+                        # the sentinel; the first quarantine retires the
+                        # page from the prefix table for later admissions
+                        owners = (kv.owners_of(page) if prefix_cache
+                                  else [slot])
+                        events.emit('fault', step=steps,
+                                    fault='poison_page', slot=slot,
+                                    page=page, owners=owners,
+                                    rid=sched.active[slot].req.rid)
+                poison_slot = None
+                if (injector is not None and sched.active
+                        and injector.poison_logits_now()):
+                    poison_slot = injector.pick(sorted(sched.active))
+                    events.emit('fault', step=steps, fault='poison_logits',
+                                slot=poison_slot,
+                                rid=sched.active[poison_slot].req.rid)
+                peak_pages = max(peak_pages, kv.used_pages)
+                if telem is not None:
+                    # gauges + hwmodel energy pricing over the step's
+                    # actual batch composition (pos/tier state is final)
+                    with span('serve.telemetry'):
+                        telem.sample(sched, kv)
+                toks, pos = sched.step_vectors()
+                cache = kvc.with_block_tables(cache, kv.table_array())
+                busy_slot_steps += len(sched.active)
+                dispatch.note(lanes=len(sched.active))
+                active_now = sorted(sched.active)
+                if oracle_check and oracle_err is None and sched.active:
+                    # the same step on the layout's einsum oracle, before
+                    # the donating kernel step consumes the cache it reads
+                    # (a sampled step's logits do not depend on its key)
+                    ref_logits = oracle_fn(params, jnp.asarray(toks),
+                                           jnp.asarray(pos), cache,
+                                           *(() if greedy else
+                                             (sample_key,)))[1]
+                try:
+                    if (injector is not None and attn_impl_live == 'flash'
+                            and injector.kernel_fault_now()):
+                        raise faults_mod.InjectedKernelError(
+                            'chaos: simulated kernel-path validation '
+                            'failure')
+                    tok, logits, cache = call_decode(cache, jnp.asarray(toks),
+                                                     jnp.asarray(pos))
+                except faults_mod.InjectedKernelError as e:
+                    # graceful degradation, exercised by the chaos layer
+                    # only: a real kernel failure propagates instead of
+                    # hiding the device. The injected failure fires before
+                    # the call, so the cache is intact — rebuild the step
+                    # on the layout's densify einsum oracle and retry
+                    events.emit('degrade', step=steps, frm='flash',
+                                to='einsum',
+                                error=f'{type(e).__name__}: {str(e)[:160]}')
+                    attn_impl_live = 'einsum'
+                    with span('serve.degrade', frm='flash', to='einsum'):
+                        decode_fn = build_decode('einsum')
+                    _decode_fns.append(decode_fn)
+                    tok, logits, cache = call_decode(cache, jnp.asarray(toks),
+                                                     jnp.asarray(pos))
+                if oracle_check and oracle_err is None and sched.active:
+                    lanes = jnp.asarray(active_now)
+                    got = logits[lanes].astype(jnp.float32)
+                    want = ref_logits[lanes].astype(jnp.float32)
+                    oracle_err = float(
+                        jnp.max(jnp.abs(got - want))
+                        / jnp.maximum(jnp.max(jnp.abs(want)), 1e-30))
+                # --- integrity sentinel: quarantine non-finite lanes -----
+                ok, logit_max = sentinel_fn(logits)
+                if poison_slot is not None:
+                    lg = np.asarray(logits, np.float32)
+                    lg[poison_slot] = np.nan
+                    ok, logit_max = sentinel_fn(jnp.asarray(lg))
+            with span('serve.wait', on='decode'):
+                ok = np.asarray(ok)
+            with span('serve.absorb'):
+                if telem is not None:
+                    # device scalar handed over as-is; telemetry
+                    # host-transfers it once at finish, never per step
+                    telem.logits_gauge(logit_max)
+                for slot in [s for s in sorted(sched.active) if not ok[s]]:
+                    # quarantine BEFORE absorb: a poisoned lane must not
+                    # finish on a garbage token (NaN argmax is id 0). The
+                    # requeue is lossless (recompute from the prompt); the
+                    # scrub keeps the poison from the page's next tenant.
+                    pages = sched.quarantine(slot)
+                    with span('serve.scrub', pages=len(pages)):
+                        cache = scrub_pages(cache, pages)
+                sched.absorb(np.asarray(tok))
+            steps += 1
+            if step_hook is not None:
+                with span('serve.hook'):
+                    step_hook(sched, kv, cache)
     jax.block_until_ready(jax.tree.leaves(cache)[0])
     wall = time.time() - t0
     if not sched.done:

@@ -25,9 +25,13 @@ decodes. This module lets a run measure itself:
   achieved bytes/token and effective TOPS/W next to the paper's targets.
 * :class:`StepTracer` — a ``--trace FILE`` Chrome-trace/Perfetto JSON
   writer: one track per decode slot plus a scheduler track, complete
-  (``ph='X'``) spans for prefill/decode/quantize/scrub/degrade phases,
-  instant events for injected faults. Load the file in ``ui.perfetto.dev``
-  or ``chrome://tracing``.
+  (``ph='X'``) spans for the loop phases, instant events for injected
+  faults. Load the file in ``ui.perfetto.dev`` or ``chrome://tracing``.
+* :class:`Span` — one named phase of the loop (``serve.step``,
+  ``serve.admit``, ``serve.dispatch``, ``serve.wait``, ...): a
+  ``jax.profiler`` annotation, so a profile puts the host's phases beside
+  the device's programs, plus, through :meth:`ServeTelemetry.span`, the
+  per-phase seconds counter, the slowest steps and the Chrome trace.
 * :class:`ServeTelemetry` — the bundle ``launch.serve.serve_continuous``
   threads through its loop; it subscribes to the :class:`EventLog` so
   every scheduler event increments ``serve_events_total{kind}`` as it is
@@ -45,10 +49,13 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import heapq
 import json
 import math
 import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import hwmodel
 from repro.runtime.faults import TERMINAL_KINDS
@@ -613,11 +620,11 @@ class EnergyMeter:
 class StepTracer:
     """Buffered Chrome-trace JSON writer (the ``--trace FILE`` surface).
 
-    Track layout: ``tid 0`` is the scheduler (quantize/scrub/degrade
-    phases, fault instants without a slot); ``tid slot+1`` is one decode
-    lane (prefill and decode spans, per-slot fault instants). All events
-    are complete (``ph='X'``) spans or instants (``ph='i'``) — no B/E
-    pairing to unbalance. Timestamps are µs relative to construction."""
+    Track layout: ``tid 0`` is the scheduler (the loop's phases, fault
+    instants without a slot); ``tid slot+1`` is one decode lane (its
+    admissions with the phases nested in them, per-slot fault instants).
+    All events are complete (``ph='X'``) spans or instants (``ph='i'``)
+    — no B/E pairing to unbalance. Timestamps are µs relative to construction."""
 
     def __init__(self, path: str, slots: int,
                  clock=time.perf_counter):
@@ -637,14 +644,14 @@ class StepTracer:
     def _us(self, t: float) -> float:
         return round((t - self.t0) * 1e6, 3)
 
-    def span(self, name: str, t_start: float, t_end: float, *,
-             slot: Optional[int] = None, **args) -> None:
+    def span(self, name: str, t_start: float, t_end: float,
+             slot: Optional[int] = None, args: Optional[dict] = None) -> None:
         self.events.append(dict(
             ph='X', name=name, pid=0,
             tid=0 if slot is None else slot + 1,
             ts=self._us(t_start),
             dur=round(max(t_end - t_start, 0.0) * 1e6, 3),
-            args=args))
+            args=args or {}))
 
     def instant(self, name: str, t: float, *,
                 slot: Optional[int] = None, **args) -> None:
@@ -660,6 +667,68 @@ class StepTracer:
 
 
 # ----------------------------------------------------------------------------
+# loop phases on the profiler's clock
+# ----------------------------------------------------------------------------
+SPAN_PREFIX = 'serve.'
+#: the span of one whole loop iteration
+STEP_SPAN = 'serve.step'
+#: how many of the longest loop steps a run keeps, with their phases
+SLOWEST_STEPS = 8
+
+
+class Span:
+    """One named phase of the serving loop (``serve.<phase>``).
+
+    Entering it always enters a ``jax.profiler.TraceAnnotation`` of the
+    same name and arguments (``serve.step`` a ``StepTraceAnnotation`` with
+    its ``step`` as the step number), so whenever a profile is recording
+    the phase lands on the profiler's host plane, on the clock of the
+    device events; when none is, that costs about a microsecond. Leaving
+    it sets ``seconds``. A span made by :meth:`ServeTelemetry.span` also
+    feeds ``serve_phase_seconds_total{phase}``, the slowest steps and the
+    ``--trace`` Chrome file, under the same name."""
+
+    __slots__ = ('name', 'args', 'owner', 'ann', 't0', 'seconds', 'inner',
+                 'slot')
+
+    def __init__(self, name: str, owner: Optional['ServeTelemetry'] = None,
+                 args: Optional[dict] = None):
+        self.name = name
+        self.args = args if args is not None else {}
+        self.owner = owner
+        self.inner = 0.0            # seconds of the spans nested in this one
+
+    def __enter__(self) -> 'Span':
+        a = self.args
+        self.ann = (StepTraceAnnotation(self.name, step_num=a['step'])
+                    if self.name == STEP_SPAN else
+                    TraceAnnotation(self.name, **a))
+        self.ann.__enter__()
+        if self.owner is not None:
+            self.owner._open(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def note(self, **args) -> None:
+        """Arguments known only once the phase has run."""
+        self.args.update(args)
+        self.ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        t_end = time.perf_counter()
+        self.seconds = t_end - self.t0
+        self.ann.__exit__(*exc)
+        if self.owner is not None:
+            self.owner._close(self, t_end)
+
+
+def span(name: str, **args) -> Span:
+    """A loop phase with no telemetry behind it (``--no-metrics``): the
+    profiler annotation and its ``seconds`` only."""
+    return Span(name, None, args)
+
+
+# ----------------------------------------------------------------------------
 # the serving bundle
 # ----------------------------------------------------------------------------
 #: event kinds that also become trace instants (faults and recoveries)
@@ -671,8 +740,8 @@ class ServeTelemetry:
     """Everything ``serve_continuous`` needs, behind one object: the
     registry, the energy meter, the optional tracer, and the EventLog
     subscription. Constructed with ``metrics=False`` it only traces (the
-    ``--no-metrics --trace X`` combination); the serve loop skips all
-    calls when neither is requested."""
+    ``--no-metrics --trace X`` combination); when neither is requested
+    the serve loop makes bare spans (:func:`span`) and no calls."""
 
     def __init__(self, cfg, *, slots: int, page_size: int,
                  kv_quant: bool = False, hot_window: int = 1, tp: int = 1,
@@ -688,7 +757,9 @@ class ServeTelemetry:
                       if self.metrics else None)
         self.tracer = (StepTracer(trace_path, slots, clock=clock)
                        if trace_path else None)
-        self._t_step0: Optional[float] = None
+        self._stack: List[Span] = []        # the open spans, innermost last
+        self._phases: Dict[str, float] = {}  # this step's self seconds
+        self._slowest: List[tuple] = []      # min-heap of the longest steps
         self._logit_max = None   # device scalar, host-transferred at finish
         if self.metrics:
             self._declare_catalog()
@@ -725,7 +796,8 @@ class ServeTelemetry:
             help='modeled attention/state MACs+adds')
         self._c_phase = r.counter(
             'serve_phase_seconds_total', labels=('phase',),
-            help='cumulative wall time by maintenance phase')
+            help='cumulative wall time by loop phase (serve.<phase> '
+                 'spans; a nested phase counts in its parent too)')
         self._g_step = r.gauge('serve_step', help='current scheduler step')
         self._g_slots = r.gauge(
             'serve_slots', labels=('state',),
@@ -773,28 +845,56 @@ class ServeTelemetry:
                                 **({'rid': ev.rid} if ev.rid is not None
                                    else {}))
 
-    # -- per-step hooks the serve loop calls ---------------------------------
-    def begin_step(self, step: int, t: float) -> None:
-        self._t_step0 = t
-        if self.metrics:
-            self._g_step.set_at((), step)
+    # -- loop phases ----------------------------------------------------------
+    def span(self, name: str, **args) -> 'Span':
+        """A loop phase that also feeds this bundle: see :class:`Span`."""
+        return Span(name, self, args)
 
-    def prefill(self, *, rid: int, slot: int, t_start: float,
-                t_end: float) -> None:
-        if self.metrics:
-            self._h_prefill.observe(t_end - t_start)
-        if self.tracer is not None:
-            self.tracer.span('prefill', t_start, t_end, slot=slot, rid=rid)
+    def _open(self, sp: 'Span') -> None:
+        stack = self._stack
+        if self.tracer is not None:     # a lane's phases go on its track
+            sp.slot = sp.args.get('slot', stack[-1].slot if stack else None)
+        stack.append(sp)
+        if sp.name == STEP_SPAN:
+            self._phases = {}
+            if self.metrics:
+                self._g_step.set_at((), sp.args['step'])
 
-    def phase(self, name: str, t_start: float, t_end: float,
-              **args) -> None:
-        """Scheduler-track maintenance phase (quantize/scrub/degrade)."""
+    def _close(self, sp: 'Span', t_end: float) -> None:
+        stack = self._stack
+        stack.pop()
+        sec = sp.seconds
+        if stack:
+            stack[-1].inner += sec
         if self.metrics:
-            self._c_phase.inc_at((name,), t_end - t_start)
-            if name == 'quantize' and args.get('pages'):
-                self._c_pquant.inc_at((), args['pages'])
+            phase = sp.name[len(SPAN_PREFIX):]
+            self._c_phase.inc_at((phase,), sec)
+            ph = self._phases
+            ph[phase] = ph.get(phase, 0.0) + sec - sp.inner
+            if sp.name == STEP_SPAN:
+                self._h_step.observe(sec)
+                self._keep_slowest(sp.args['step'], sec, ph)
+            elif sp.name == 'serve.prefill':
+                self._h_prefill.observe(sec)
+            elif sp.name == 'serve.quantize' and sp.args.get('pages'):
+                self._c_pquant.inc_at((), sp.args['pages'])
         if self.tracer is not None:
-            self.tracer.span(name, t_start, t_end, **args)
+            self.tracer.span(sp.name, sp.t0, t_end, sp.slot, sp.args)
+
+    def _keep_slowest(self, step: int, sec: float, phases: dict) -> None:
+        heap = self._slowest
+        if len(heap) < SLOWEST_STEPS:
+            heapq.heappush(heap, (sec, step, phases))
+        elif sec > heap[0][0]:
+            heapq.heapreplace(heap, (sec, step, phases))
+
+    def slowest_steps(self) -> List[dict]:
+        """The longest loop steps, longest first: each with its step
+        number, wall seconds and per-phase self seconds (a phase's own
+        time, less the phases nested in it; ``step`` is what no phase
+        covers), which sum to the wall seconds."""
+        return [dict(step=step, seconds=sec, phases=dict(sorted(ph.items())))
+                for sec, step, ph in sorted(self._slowest, reverse=True)]
 
     def sample(self, sched, kv) -> None:
         """Once per step, pre-decode: scheduler/allocator gauges and the
@@ -859,12 +959,6 @@ class ServeTelemetry:
         pj.inc_at(('baseline',), inc['baseline_pj'])
         self._c_ops.inc_at((), inc['ops'])
 
-    def decode(self, t_start: float, t_end: float,
-               active_slots: Iterable[int]) -> None:
-        if self.tracer is not None:
-            for slot in active_slots:
-                self.tracer.span('decode', t_start, t_end, slot=slot)
-
     def logits_gauge(self, max_abs) -> None:
         """Takes the sentinel's max-|logit| as-is — a jax device scalar
         stays on device; the single host transfer happens at
@@ -872,10 +966,6 @@ class ServeTelemetry:
         than the whole rest of the instrumentation)."""
         if self.metrics:
             self._logit_max = max_abs
-
-    def step_done(self, t_end: float) -> None:
-        if self.metrics and self._t_step0 is not None:
-            self._h_step.observe(t_end - self._t_step0)
 
     # -- finalization --------------------------------------------------------
     def finish(self, events) -> Optional[dict]:
@@ -890,7 +980,8 @@ class ServeTelemetry:
         observe_spans(self.reg, spans)
         return dict(metrics=self.reg.snapshot(),
                     energy=self.meter.totals(),
-                    spans=len(spans))
+                    spans=len(spans),
+                    slowest_steps=self.slowest_steps())
 
     def close_trace(self) -> Optional[str]:
         if self.tracer is None:

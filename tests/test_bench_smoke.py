@@ -100,4 +100,5 @@ def test_bench_chaos_smoke_asserts_accounting(tmp_path):
     assert mo['overhead_frac'] < mo['budget']
     assert mo['bare_step_s'] > 0 and mo['instrumented_step_s'] > 0
     assert on_disk['trace']['trace_events'] > 0
-    assert {'prefill', 'decode'} <= set(on_disk['trace']['span_names'])
+    assert {'serve.step', 'serve.prefill', 'serve.dispatch'} <= \
+        set(on_disk['trace']['span_names'])

@@ -348,7 +348,8 @@ def test_trace_file_is_loadable_chrome_trace(tmp_path):
             assert e['ts'] >= 0 and e['dur'] >= 0
             assert 0 <= e['tid'] <= SMOKE['slots']
     names = {e['name'] for e in evs if e['ph'] == 'X'}
-    assert {'prefill', 'decode'} <= names
+    assert {'serve.step', 'serve.admit', 'serve.prefill', 'serve.dispatch',
+            'serve.wait'} <= names
     # one named track per slot plus the scheduler track
     threads = {e['args']['name'] for e in evs
                if e['ph'] == 'M' and e['name'] == 'thread_name'}

@@ -245,3 +245,49 @@ def test_stablelm_tp4_decode_step_full_width(topo, compile_tpu, monkeypatch):
     txt = jax.jit(step).lower(placed(params, pspecs), vec, vec,
                               placed(cache, cspecs)).compile().as_text()
     assert 'tpu_custom_call' in txt and 'all-gather' in txt
+
+
+def _reader_kernel(metric):
+    """The kernel pattern a benchmark reader matches in the trace."""
+    import importlib.util
+    from chipbench.harness import cell
+    spec = importlib.util.spec_from_file_location(
+        'reader', cell.reader_path(metric))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNEL
+
+
+@pytest.mark.parametrize('metric', ['decode_attn_roofline',
+                                    'decode_attn_q8_roofline',
+                                    'w8a8_matmul_roofline'])
+def test_kernel_names_the_benchmark_reads(compile_tpu, metric):
+    """Each roofline reader finds its kernel by the name of its custom
+    call in the trace's op events (less the numeric suffix): the compiled
+    program must carry exactly that name."""
+    import re
+    from chipbench.harness import trace as trace_mod
+    hkv = 8
+    if metric == 'decode_attn_roofline':
+        txt = compile_tpu(
+            lambda q, k, v, p, bt: fd.flash_decode_paged(
+                q, k, v, p, bt, scale=0.125, interpret=False),
+            ((B, 1, hkv, DH), BF), _kv(hkv), _kv(hkv), ((B,), I32),
+            ((B, W), I32))
+    elif metric == 'decode_attn_q8_roofline':
+        txt = compile_tpu(
+            lambda q, k, v, kq, vq, ks, vs, p, bt, hw:
+            fd.flash_decode_paged_q8(q, k, v, kq, vq, ks, vs, p, bt, hw,
+                                     scale=0.125, interpret=False),
+            ((B, 1, hkv, DH), BF), _kv(hkv), _kv(hkv), _kv(hkv, I8),
+            _kv(hkv, I8), ((P, hkv), F32), ((P, hkv), F32), ((B,), I32),
+            ((B, W), I32), ((1,), I32))
+    else:
+        txt = compile_tpu(
+            lambda x, w: yoco_vmm.int8_matmul(x, w, interpret=False),
+            ((128, 2048), I8), ((2048, 5632), I8))
+    calls = [trace_mod.op_label(line.strip().removeprefix('ROOT '))
+             for line in txt.splitlines()
+             if ' custom-call(' in line and 'tpu_custom_call' in line]
+    rx = re.compile(_reader_kernel(metric))
+    assert calls and all(rx.search(c) for c in calls), calls
