@@ -73,6 +73,10 @@ cache is shared by every head) and keep all H queries resident in the
 one cell. Per-page int8 scales ride as ``(P, Hkv, 1)`` / ``(P, 1, 1)``
 VMEM blocks for the same tiling reason.
 
+Every entrypoint also takes an optional ``layer`` operand: the tensors are
+then the layer-stacked buffers the model's layer scan carries, and the
+grid reads layer ``layer`` of them in place (:func:`_flash_core`).
+
 CPU CI runs these same kernel bodies with ``interpret=True``.
 """
 
@@ -203,7 +207,7 @@ def _core_kernel(*refs, ns: int, nt: int, loader, bs: int, s_steps: int,
 def _flash_core(q: jnp.ndarray, scalars, tensors, tensor_specs, *, loader,
                 out_width: int, bs: int, s_steps: int, scale: float,
                 interpret: bool, chunk: int = 1, group: int = 1,
-                off_idx=None) -> jnp.ndarray:
+                off_idx=None, layer=None) -> jnp.ndarray:
     """Run the flash-decode grid over ``q`` (B, Hg, rows, dk) with a
     layout-supplied ``(index_maps, loader)`` pair: ``tensor_specs`` carry
     the layout's data-dependent index maps (one BlockSpec per tensor
@@ -212,9 +216,25 @@ def _flash_core(q: jnp.ndarray, scalars, tensors, tensor_specs, *, loader,
     rule), ``loader`` turns the fetched refs into f32 head-major K/V
     tiles. ``scalars`` ride in scalar-prefetch operands (pos and window
     first — the core reads those itself). Returns (B, Hg, rows, out_width)
-    f32."""
+    f32.
+
+    ``layer`` (a traced int32 scalar, or None): every tensor operand one
+    rank above its block is a layer-stacked buffer ``(L, ...)`` and the
+    grid reads layer ``layer`` of it in place. The index rides as the last
+    scalar-prefetch operand; each such tensor's BlockSpec gains a squeezed
+    leading block dim whose index is that layer, so the layout's maps and
+    loader see exactly the single-layer blocks and refs they would without
+    it, and the kernel DMAs the live pages straight out of the stacked
+    pool (no per-layer copy of the pool is ever made). A tensor at its
+    block's rank is one layer's already (the q8 wrappers slice the small
+    per-page scales at ``layer`` before the call) and is read as it is."""
     b, hg, rows, dk = q.shape
     grid = (b, s_steps)
+    if layer is not None:
+        scalars = tuple(scalars) + (
+            jnp.asarray(layer, jnp.int32).reshape(1),)
+        tensor_specs = [_layer_spec(spec, t.ndim > len(spec.block_shape))
+                        for t, spec in zip(tensors, tensor_specs)]
 
     def qo_map(bb, s, *sr):
         del s, sr
@@ -246,6 +266,21 @@ def _flash_core(q: jnp.ndarray, scalars, tensors, tensor_specs, *, loader,
     )(*scalars, q, *tensors)
 
 
+def _layer_spec(spec: pl.BlockSpec, stacked: bool) -> pl.BlockSpec:
+    """A single-layer tensor BlockSpec under a layer operand (the last
+    scalar-prefetch ref, hidden from the layout's own map). ``stacked``:
+    lifted onto the layer-stacked buffer, a squeezed leading block dim
+    indexed by the layer."""
+    inner = spec.index_map
+    if not stacked:
+        return pl.BlockSpec(spec.block_shape,
+                            lambda bb, s, *sr: inner(bb, s, *sr[:-1]))
+
+    def index_map(bb, s, *sr):
+        return (sr[-1][0],) + tuple(inner(bb, s, *sr[:-1]))
+    return pl.BlockSpec((None,) + tuple(spec.block_shape), index_map)
+
+
 def _clamped_block(s, pos_ref, win_ref, b, bs: int):
     """Block index actually fetched at grid step ``s``: dead steps revisit
     the nearest live block so their DMA is elided by the pipeline."""
@@ -268,7 +303,8 @@ def _fp_loader(scalars, t_refs, bb, s, pos, win):
 @functools.partial(jax.jit,
                    static_argnames=('scale', 'bs', 'prefetch', 'interpret'))
 def flash_decode_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     pos: jnp.ndarray, window: jnp.ndarray, *,
+                     pos: jnp.ndarray, window: jnp.ndarray,
+                     layer: jnp.ndarray = None, *,
                      scale: float, bs: int = DEFAULT_BS,
                      prefetch: bool = True,
                      interpret: bool = False) -> jnp.ndarray:
@@ -280,6 +316,8 @@ def flash_decode_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     pos:    (B,) int32      — per-request absolute position; keys at
                               kpos <= pos[b] are attended
     window: (B,) int32      — per-request sliding window (>= S+1 disables)
+    layer:  None, or int32 scalar — k/v are layer-stacked (L, B, S, Hkv,
+            dh) caches and the kernel reads layer ``layer`` in place
     prefetch: data-dependent index maps (dead tiles never fetched). False
               is the legacy streamed baseline: identity maps, every tile
               DMA'd, masked tiles skip compute only. Same harness, same
@@ -288,8 +326,8 @@ def flash_decode_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Returns (B, Hkv, G, dh) f32.
     """
     b, hkv, g, dh = q.shape
-    s_max = k.shape[1]
-    assert k.shape == (b, s_max, hkv, dh) and v.shape == k.shape, \
+    s_max = k.shape[-3]
+    assert k.shape[-4:] == (b, s_max, hkv, dh) and v.shape == k.shape, \
         (q.shape, k.shape, v.shape)
     assert s_max % bs == 0, (s_max, bs)
     assert pos.shape == (b,) and window.shape == (b,)
@@ -311,7 +349,7 @@ def flash_decode_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       pl.BlockSpec((1, bs, hkv, dh), kv_map)],
         loader=_fp_loader,
         out_width=dh, bs=bs, s_steps=s_steps, scale=scale,
-        interpret=interpret)
+        interpret=interpret, layer=layer)
 
 
 # ----------------------------------------------------------------------------
@@ -322,7 +360,8 @@ def flash_decode_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, pos: jnp.ndarray,
                            window: jnp.ndarray, block_tables: jnp.ndarray,
-                           offset: jnp.ndarray = None, *, scale: float,
+                           offset: jnp.ndarray = None,
+                           layer: jnp.ndarray = None, *, scale: float,
                            chunk: int = 1,
                            interpret: bool = False) -> jnp.ndarray:
     """Single-token GQA decode attention over a *paged* KV pool.
@@ -335,6 +374,9 @@ def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     block_tables: (B, W) int32 — logical key block i of request b lives in
                   physical page block_tables[b, i]; W bounds the grid's S
                   dimension (size it to ceil(max_live / page_size))
+    layer:        None, or int32 scalar — the pools are layer-stacked
+                  (L, P, page_size, Hkv, dh) and the grid DMAs layer
+                  ``layer``'s pages straight out of the stack
 
     ``chunk > 1`` is the chunked-prefill shape: the G axis widens to
     chunk * G (row i = query head i % G at position offset[b] + i // G),
@@ -347,7 +389,7 @@ def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     Returns (B, Hkv, G, dh) f32 (chunked: (B, Hkv, chunk * G, dh)).
     """
     b, hkv, g, dh = q.shape
-    _, page_size, hkv_k, dh_k = k_pages.shape
+    page_size, hkv_k, dh_k = k_pages.shape[-3:]
     assert (hkv_k, dh_k) == (hkv, dh), (q.shape, k_pages.shape)
     assert v_pages.shape == k_pages.shape
     assert pos.shape == (b,) and window.shape == (b,)
@@ -374,7 +416,7 @@ def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         loader=_fp_loader,
         out_width=dh, bs=page_size, s_steps=s_steps, scale=scale,
         interpret=interpret, chunk=chunk, group=g // chunk,
-        off_idx=3 if chunk > 1 else None)
+        off_idx=3 if chunk > 1 else None, layer=layer)
 
 
 # ----------------------------------------------------------------------------
@@ -385,7 +427,8 @@ def flash_decode_gqa_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
 def flash_decode_mla_paged(q: jnp.ndarray, c_pages: jnp.ndarray,
                            pos: jnp.ndarray, window: jnp.ndarray,
                            block_tables: jnp.ndarray,
-                           offset: jnp.ndarray = None, *, scale: float,
+                           offset: jnp.ndarray = None,
+                           layer: jnp.ndarray = None, *, scale: float,
                            r: int, chunk: int = 1,
                            interpret: bool = False) -> jnp.ndarray:
     """Single-token absorbed-MLA decode attention over a *paged* latent pool.
@@ -407,6 +450,8 @@ def flash_decode_mla_paged(q: jnp.ndarray, c_pages: jnp.ndarray,
                   nearest live block so their DMA is elided
     r:            static latent rank — the value width (``W_uv`` is applied
                   once OUTSIDE the kernel, on the normalized output)
+    layer:        None, or int32 scalar — ``c_pages`` is the layer-stacked
+                  (L, P, page_size, r + d_rope) pool, read in place
 
     ``chunk > 1`` widens the resident H axis to chunk * H (row i = head
     i % H at position offset[b] + i // H) with the same fetch-union
@@ -418,7 +463,7 @@ def flash_decode_mla_paged(q: jnp.ndarray, c_pages: jnp.ndarray,
     """
     b, one, h, dk = q.shape
     assert one == 1, q.shape
-    _, page_size, dk_c = c_pages.shape
+    page_size, dk_c = c_pages.shape[-2:]
     assert dk_c == dk, (q.shape, c_pages.shape)
     assert 0 < r < dk, (r, dk)
     assert pos.shape == (b,) and window.shape == (b,)
@@ -452,7 +497,7 @@ def flash_decode_mla_paged(q: jnp.ndarray, c_pages: jnp.ndarray,
         loader=mla_loader,
         out_width=r, bs=page_size, s_steps=s_steps, scale=scale,
         interpret=interpret, chunk=chunk, group=h // chunk,
-        off_idx=3 if chunk > 1 else None)
+        off_idx=3 if chunk > 1 else None, layer=layer)
 
 
 # ----------------------------------------------------------------------------
@@ -513,7 +558,8 @@ def flash_decode_gqa_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
                               v_scales: jnp.ndarray, pos: jnp.ndarray,
                               window: jnp.ndarray,
                               block_tables: jnp.ndarray,
-                              hot_window: jnp.ndarray, *, scale: float,
+                              hot_window: jnp.ndarray,
+                              layer: jnp.ndarray = None, *, scale: float,
                               interpret: bool = False) -> jnp.ndarray:
     """:func:`flash_decode_gqa_paged` over a hybrid-precision pool pair.
 
@@ -526,6 +572,12 @@ def flash_decode_gqa_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
     k/v_scales:   (P, Hkv) f32 per-page, per-head absmax scales
     hot_window:   (1,) int32, in pages, >= 1 (the page being written is
                   always hot). >= W reads everything from the fp pool.
+    layer:        None, or int32 scalar — pools and scales are
+                  layer-stacked (a leading (L,) dim); the pools are read
+                  in place, the scales sliced at ``layer`` first (the
+                  kernel's (P, Hkv, 1) view of them is a relayout, which
+                  over the whole stack would copy every layer's scales
+                  on every call)
 
     Block ``s`` of a request at ``pos`` is hot iff
     ``s > pos // page_size - hot_window``; a hot grid step fetches the fp
@@ -535,17 +587,19 @@ def flash_decode_gqa_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
     broadcast along the fetched tile's head axis.
     """
     b, hkv, g, dh = q.shape
-    n_pages, page_size, hkv_k, dh_k = k_pages.shape
+    page_size, hkv_k, dh_k = k_pages.shape[-3:]
     assert (hkv_k, dh_k) == (hkv, dh), (q.shape, k_pages.shape)
     assert v_pages.shape == k_pages.shape
     assert kq_pages.shape == k_pages.shape and kq_pages.dtype == jnp.int8
     assert vq_pages.shape == k_pages.shape and vq_pages.dtype == jnp.int8
-    assert k_scales.shape == v_scales.shape == (n_pages, hkv)
+    assert k_scales.shape == v_scales.shape == k_pages.shape[:-3] + (hkv,)
     assert pos.shape == (b,) and window.shape == (b,)
     assert block_tables.ndim == 2 and block_tables.shape[0] == b
     assert hot_window.shape == (1,)
     s_steps = block_tables.shape[1]
     fp_map, q8_map, scale_map = _tier_maps(page_size, 4)
+    if layer is not None:
+        k_scales, v_scales = k_scales[layer], v_scales[layer]
 
     def q8_loader(scalars, t_refs, bb, s, pos_, win_):
         k_ref, v_ref, kq_ref, vq_ref, ks_ref, vs_ref = t_refs
@@ -567,8 +621,8 @@ def flash_decode_gqa_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
                  block_tables.astype(jnp.int32),
                  hot_window.astype(jnp.int32)),
         tensors=(k_pages, v_pages, kq_pages, vq_pages,
-                 k_scales.astype(jnp.float32).reshape(n_pages, hkv, 1),
-                 v_scales.astype(jnp.float32).reshape(n_pages, hkv, 1)),
+                 k_scales.astype(jnp.float32)[..., None],
+                 v_scales.astype(jnp.float32)[..., None]),
         tensor_specs=[
             pl.BlockSpec(kv_block, fp_map),
             pl.BlockSpec(kv_block, fp_map),
@@ -579,7 +633,7 @@ def flash_decode_gqa_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
         ],
         loader=q8_loader,
         out_width=dh, bs=page_size, s_steps=s_steps, scale=scale,
-        interpret=interpret)
+        interpret=interpret, layer=layer)
 
 
 @functools.partial(jax.jit,
@@ -588,7 +642,8 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
                               cq_pages: jnp.ndarray, c_scales: jnp.ndarray,
                               pos: jnp.ndarray, window: jnp.ndarray,
                               block_tables: jnp.ndarray,
-                              hot_window: jnp.ndarray, *, scale: float,
+                              hot_window: jnp.ndarray,
+                              layer: jnp.ndarray = None, *, scale: float,
                               r: int, interpret: bool = False) -> jnp.ndarray:
     """:func:`flash_decode_mla_paged` over a hybrid-precision latent pool.
 
@@ -600,6 +655,9 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
     c_scales:  (P, 1) f32 per-page absmax scales
     hot_window: (1,) int32, in pages, >= 1; >= W never reads the int8 tier
                (bit-exact with :func:`flash_decode_mla_paged`)
+    layer:     None, or int32 scalar — pools and scales are layer-stacked
+               (a leading (L,) dim); pools read in place, scales sliced at
+               ``layer`` first (as :func:`flash_decode_gqa_paged_q8`)
 
     Same hotness rule and tier routing as :func:`flash_decode_gqa_paged_q8`
     (one tier's DMA per tile, dequant in VMEM rounded through the serving
@@ -609,16 +667,18 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
     """
     b, one, h, dk = q.shape
     assert one == 1, q.shape
-    n_pages, page_size, dk_c = c_pages.shape
+    page_size, dk_c = c_pages.shape[-2:]
     assert dk_c == dk, (q.shape, c_pages.shape)
     assert 0 < r < dk, (r, dk)
     assert cq_pages.shape == c_pages.shape and cq_pages.dtype == jnp.int8
-    assert c_scales.shape == (n_pages, 1), c_scales.shape
+    assert c_scales.shape == c_pages.shape[:-2] + (1,), c_scales.shape
     assert pos.shape == (b,) and window.shape == (b,)
     assert block_tables.ndim == 2 and block_tables.shape[0] == b
     assert hot_window.shape == (1,)
     s_steps = block_tables.shape[1]
     c_fp_map, c_q8_map, cs_map = _tier_maps(page_size, 3)
+    if layer is not None:
+        c_scales = c_scales[layer]
 
     def mla_q8_loader(scalars, t_refs, bb, s, pos_, win_):
         c_ref, cq_ref, cs_ref = t_refs
@@ -636,7 +696,7 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
                  block_tables.astype(jnp.int32),
                  hot_window.astype(jnp.int32)),
         tensors=(c_pages, cq_pages,
-                 c_scales.astype(jnp.float32).reshape(n_pages, 1, 1)),
+                 c_scales.astype(jnp.float32)[..., None]),
         tensor_specs=[
             pl.BlockSpec((1, page_size, dk), c_fp_map),
             pl.BlockSpec((1, page_size, dk), c_q8_map),
@@ -644,7 +704,7 @@ def flash_decode_mla_paged_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
         ],
         loader=mla_q8_loader,
         out_width=r, bs=page_size, s_steps=s_steps, scale=scale,
-        interpret=interpret)
+        interpret=interpret, layer=layer)
 
 
 # ----------------------------------------------------------------------------
@@ -700,7 +760,8 @@ def _interpret_default(interpret):
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  pos: jnp.ndarray, *, scale: float,
                  window=None, bs: int = DEFAULT_BS,
-                 interpret=None, impl: str = 'prefetch') -> jnp.ndarray:
+                 interpret=None, impl: str = 'prefetch',
+                 layer=None) -> jnp.ndarray:
     """Shape-flexible wrapper around the contiguous flash-decode kernel.
 
     q:   (B, 1, H, dh) or (B, H, dh) — the single decode-step query
@@ -710,6 +771,9 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     impl: 'prefetch' (scalar-prefetch block skipping, default) or
           'streamed' (legacy: every tile DMA'd; kept as the benchmark
           baseline for the dead-tile bandwidth comparison)
+    layer: None, or int32 scalar — k/v are layer-stacked (L, B, S_max,
+           Hkv, dh) caches, read at ``layer`` in place (a cache whose
+           S_max needs tile padding pads that one layer's slice instead)
 
     Returns attention output shaped like q, in v.dtype (the one conversion
     back to the serving dtype happens here, after the fused normalize).
@@ -720,7 +784,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         assert q.shape[1] == 1, q.shape
         q = q[:, 0]
     b, h, dh = q.shape
-    s_max, hkv = k.shape[1], k.shape[2]
+    s_max, hkv = k.shape[-3], k.shape[-2]
     g = h // hkv
     qg = q.reshape(b, hkv, g, dh)      # same (hkv, g) grouping as _sdpa
     pos = _norm_scalar_vec(pos, b)
@@ -728,10 +792,12 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bs_eff = _pick_bs(s_max, min(bs, _vmem_bs_cap(hkv, dh)))
     pad = (-s_max) % bs_eff
     if pad:
+        if layer is not None:
+            k, v, layer = k[layer], v[layer], None
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    out = flash_decode_gqa(qg, k, v, pos, win, scale=scale, bs=bs_eff,
-                           prefetch=(impl == 'prefetch'),
+    out = flash_decode_gqa(qg, k, v, pos, win, layer, scale=scale,
+                           bs=bs_eff, prefetch=(impl == 'prefetch'),
                            interpret=_interpret_default(interpret))
     out = out.reshape(b, h, dh).astype(v.dtype)
     return out[:, None] if squeeze else out
@@ -740,11 +806,13 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                        v_pages: jnp.ndarray, pos: jnp.ndarray,
                        block_tables: jnp.ndarray, *, scale: float,
-                       window=None, interpret=None) -> jnp.ndarray:
+                       window=None, interpret=None,
+                       layer=None) -> jnp.ndarray:
     """Shape-flexible wrapper around :func:`flash_decode_gqa_paged`.
 
-    q: (B, 1, H, dh) or (B, H, dh); k/v_pages: (P, page_size, Hkv, dh);
-    pos: scalar or (B,); block_tables: (B, W) int32.
+    q: (B, 1, H, dh) or (B, H, dh); k/v_pages: (P, page_size, Hkv, dh), or
+    layer-stacked (L, P, page_size, Hkv, dh) read at ``layer``; pos:
+    scalar or (B,); block_tables: (B, W) int32.
 
     Returns attention output shaped like q, in v_pages.dtype.
     """
@@ -753,14 +821,14 @@ def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         assert q.shape[1] == 1, q.shape
         q = q[:, 0]
     b, h, dh = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[-2]
     g = h // hkv
     qg = q.reshape(b, hkv, g, dh)
-    s_logical = block_tables.shape[1] * k_pages.shape[1]
+    s_logical = block_tables.shape[1] * k_pages.shape[-3]
     pos = _norm_scalar_vec(pos, b)
     win = _norm_scalar_vec(window, b, fill=s_logical + 1)
     out = flash_decode_gqa_paged(qg, k_pages, v_pages, pos, win,
-                                 block_tables, scale=scale,
+                                 block_tables, None, layer, scale=scale,
                                  interpret=_interpret_default(interpret))
     out = out.reshape(b, h, dh).astype(v_pages.dtype)
     return out[:, None] if squeeze else out
@@ -769,11 +837,12 @@ def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
 def flash_decode_paged_mla(q: jnp.ndarray, c_pages: jnp.ndarray,
                            pos: jnp.ndarray, block_tables: jnp.ndarray, *,
                            r: int, scale: float, window=None,
-                           interpret=None) -> jnp.ndarray:
+                           interpret=None, layer=None) -> jnp.ndarray:
     """Shape-flexible wrapper around :func:`flash_decode_mla_paged`.
 
     q: (B, 1, H, r + d_rope) or (B, H, r + d_rope) — the absorbed+rope
-    query; c_pages: (P, page_size, r + d_rope) latent pool; pos: scalar or
+    query; c_pages: (P, page_size, r + d_rope) latent pool, or the
+    layer-stacked (L, P, ...) pool read at ``layer``; pos: scalar or
     (B,); block_tables: (B, W) int32; ``r``: static latent rank.
 
     Returns the latent-space attention output shaped like q with last dim
@@ -786,11 +855,11 @@ def flash_decode_paged_mla(q: jnp.ndarray, c_pages: jnp.ndarray,
     else:
         q = q[:, None]               # (B, H, dk) -> (B, 1, H, dk)
     b = q.shape[0]
-    s_logical = block_tables.shape[1] * c_pages.shape[1]
+    s_logical = block_tables.shape[1] * c_pages.shape[-2]
     pos = _norm_scalar_vec(pos, b)
     win = _norm_scalar_vec(window, b, fill=s_logical + 1)
     out = flash_decode_mla_paged(q, c_pages, pos, win, block_tables,
-                                 scale=scale, r=r,
+                                 None, layer, scale=scale, r=r,
                                  interpret=_interpret_default(interpret))
     return out if had_q_axis else out[:, 0]
 
@@ -801,12 +870,14 @@ def flash_decode_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
                           v_scales: jnp.ndarray, pos: jnp.ndarray,
                           block_tables: jnp.ndarray,
                           hot_window: jnp.ndarray, *, scale: float,
-                          window=None, interpret=None) -> jnp.ndarray:
+                          window=None, interpret=None,
+                          layer=None) -> jnp.ndarray:
     """Shape-flexible wrapper around :func:`flash_decode_gqa_paged_q8`.
 
     q: (B, 1, H, dh) or (B, H, dh); pools: (P, page_size, Hkv, dh) fp +
     int8 pair; scales: (P, Hkv); pos: scalar or (B,); block_tables:
-    (B, W) int32; hot_window: int or (1,) int32.
+    (B, W) int32; hot_window: int or (1,) int32. With ``layer``, pools and
+    scales are layer-stacked (a leading (L,) dim), read at ``layer``.
 
     Returns attention output shaped like q, in v_pages.dtype.
     """
@@ -815,16 +886,16 @@ def flash_decode_paged_q8(q: jnp.ndarray, k_pages: jnp.ndarray,
         assert q.shape[1] == 1, q.shape
         q = q[:, 0]
     b, h, dh = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[-2]
     g = h // hkv
     qg = q.reshape(b, hkv, g, dh)
-    s_logical = block_tables.shape[1] * k_pages.shape[1]
+    s_logical = block_tables.shape[1] * k_pages.shape[-3]
     pos = _norm_scalar_vec(pos, b)
     win = _norm_scalar_vec(window, b, fill=s_logical + 1)
     hw = jnp.asarray(hot_window, jnp.int32).reshape(-1)[:1]
     out = flash_decode_gqa_paged_q8(qg, k_pages, v_pages, kq_pages,
                                     vq_pages, k_scales, v_scales, pos, win,
-                                    block_tables, hw, scale=scale,
+                                    block_tables, hw, layer, scale=scale,
                                     interpret=_interpret_default(interpret))
     out = out.reshape(b, h, dh).astype(v_pages.dtype)
     return out[:, None] if squeeze else out
@@ -835,13 +906,14 @@ def flash_decode_paged_mla_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
                               pos: jnp.ndarray, block_tables: jnp.ndarray,
                               hot_window: jnp.ndarray, *, r: int,
                               scale: float, window=None,
-                              interpret=None) -> jnp.ndarray:
+                              interpret=None, layer=None) -> jnp.ndarray:
     """Shape-flexible wrapper around :func:`flash_decode_mla_paged_q8`.
 
     q: (B, 1, H, r + d_rope) or (B, H, r + d_rope); c_pages fp +
     cq_pages int8: (P, page_size, r + d_rope); c_scales: (P, 1) or (P,);
     pos: scalar or (B,); block_tables: (B, W) int32; hot_window: int or
-    (1,) int32; ``r``: static latent rank.
+    (1,) int32; ``r``: static latent rank. With ``layer``, pools and
+    scales are layer-stacked (a leading (L,) dim), read at ``layer``.
 
     Returns the latent-space attention output shaped like q with last dim
     ``r``, in f32 (the caller applies ``W_uv`` once and converts).
@@ -852,13 +924,15 @@ def flash_decode_paged_mla_q8(q: jnp.ndarray, c_pages: jnp.ndarray,
     else:
         q = q[:, None]
     b = q.shape[0]
-    s_logical = block_tables.shape[1] * c_pages.shape[1]
+    s_logical = block_tables.shape[1] * c_pages.shape[-2]
     pos = _norm_scalar_vec(pos, b)
     win = _norm_scalar_vec(window, b, fill=s_logical + 1)
     hw = jnp.asarray(hot_window, jnp.int32).reshape(-1)[:1]
-    cs = jnp.asarray(c_scales, jnp.float32).reshape(c_pages.shape[0], 1)
+    cs = jnp.asarray(c_scales, jnp.float32).reshape(c_pages.shape[:-2]
+                                                    + (1,))
     out = flash_decode_mla_paged_q8(q, c_pages, cq_pages, cs, pos, win,
-                                    block_tables, hw, scale=scale, r=r,
+                                    block_tables, hw, layer, scale=scale,
+                                    r=r,
                                     interpret=_interpret_default(interpret))
     return out if had_q_axis else out[:, 0]
 
@@ -882,29 +956,31 @@ def _chunk_scalars(offset, limit, window, b: int, c: int, s_logical: int):
 def flash_chunk_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                       v_pages: jnp.ndarray, offset, limit,
                       block_tables: jnp.ndarray, *, scale: float,
-                      window=None, interpret=None) -> jnp.ndarray:
+                      window=None, interpret=None,
+                      layer=None) -> jnp.ndarray:
     """Chunked-prefill GQA attention over a paged KV pool: the chunk's C
     query tokens (absolute positions offset .. offset + C - 1) causally
     attend everything already written for the request, including the
     chunk's own rows (write the chunk to the pool FIRST, then call this).
 
-    q: (B, C, H, dh); k/v_pages: (P, page_size, Hkv, dh); offset/limit:
-    scalar or (B,) — rows at positions >= limit are padding (their
-    outputs are finite garbage; the caller discards them);
-    block_tables: (B, W) int32.
+    q: (B, C, H, dh); k/v_pages: (P, page_size, Hkv, dh), or
+    layer-stacked (L, P, ...) read at ``layer``; offset/limit: scalar or
+    (B,) — rows at positions >= limit are padding (their outputs are
+    finite garbage; the caller discards them); block_tables: (B, W) int32.
 
     Returns (B, C, H, dh) in v_pages.dtype.
     """
     b, c, h, dh = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[-2]
     g = h // hkv
     # (B, C, Hkv, G, dh) -> (B, Hkv, C, G, dh) -> rows = C * G per KV head
     qg = q.reshape(b, c, hkv, g, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(b, hkv, c * g, dh)
-    s_logical = block_tables.shape[1] * k_pages.shape[1]
+    s_logical = block_tables.shape[1] * k_pages.shape[-3]
     offv, posv, winv = _chunk_scalars(offset, limit, window, b, c, s_logical)
     out = flash_decode_gqa_paged(qg, k_pages, v_pages, posv, winv,
-                                 block_tables, offv, scale=scale, chunk=c,
+                                 block_tables, offv, layer, scale=scale,
+                                 chunk=c,
                                  interpret=_interpret_default(interpret))
     out = out.reshape(b, hkv, c, g, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(b, c, h, dh)
@@ -914,21 +990,22 @@ def flash_chunk_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
 def flash_chunk_paged_mla(q: jnp.ndarray, c_pages: jnp.ndarray, offset,
                           limit, block_tables: jnp.ndarray, *, r: int,
                           scale: float, window=None,
-                          interpret=None) -> jnp.ndarray:
+                          interpret=None, layer=None) -> jnp.ndarray:
     """Chunked-prefill absorbed-MLA attention over a paged latent pool:
     same contract as :func:`flash_chunk_paged` with the absorbed query
     layout of :func:`flash_decode_paged_mla`.
 
-    q: (B, C, H, r + d_rope); c_pages: (P, page_size, r + d_rope);
-    offset/limit: scalar or (B,); block_tables: (B, W) int32.
+    q: (B, C, H, r + d_rope); c_pages: (P, page_size, r + d_rope), or
+    layer-stacked (L, P, ...) read at ``layer``; offset/limit: scalar or
+    (B,); block_tables: (B, W) int32.
 
     Returns (B, C, H, r) f32 (the caller applies ``W_uv`` once).
     """
     b, c, h, dk = q.shape
     qg = q.reshape(b, 1, c * h, dk)      # row i -> position off + i // H
-    s_logical = block_tables.shape[1] * c_pages.shape[1]
+    s_logical = block_tables.shape[1] * c_pages.shape[-2]
     offv, posv, winv = _chunk_scalars(offset, limit, window, b, c, s_logical)
     out = flash_decode_mla_paged(qg, c_pages, posv, winv, block_tables,
-                                 offv, scale=scale, r=r, chunk=c,
+                                 offv, layer, scale=scale, r=r, chunk=c,
                                  interpret=_interpret_default(interpret))
     return out.reshape(b, c, h, r)

@@ -85,7 +85,8 @@ def init_mla(key: jax.Array, cfg) -> dict:
 def init_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16,
                n_sites: int = 0) -> dict:
     """Empty KV cache. ``n_sites`` > 0 prepends a site dim (zamba2 shared
-    blocks: one cache per application site)."""
+    blocks: one cache per application site). GQA rows are stored
+    ``kv_cache.row_width`` wide, as in :func:`init_paged_cache`."""
     lead = (n_sites,) if n_sites else ()
     if cfg.mla is not None:
         m = cfg.mla
@@ -93,7 +94,8 @@ def init_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16,
             ckv=jnp.zeros(lead + (batch, max_seq, m.kv_lora_rank), dtype),
             krope=jnp.zeros(lead + (batch, max_seq, m.rope_head_dim), dtype),
         )
-    hkv, dh = cfg.n_kv_heads, cfg.resolved_head_dim
+    from repro.runtime.kv_cache import row_width
+    hkv, dh = cfg.n_kv_heads, row_width(cfg.resolved_head_dim)
     return dict(
         k=jnp.zeros(lead + (batch, max_seq, hkv, dh), dtype),
         v=jnp.zeros(lead + (batch, max_seq, hkv, dh), dtype),
@@ -118,7 +120,12 @@ def init_paged_cache(cfg, batch: int, *, num_pages: int, page_size: int,
     (``runtime.layouts.PagedMLAQ8Layout``) quantizes cold latent pages
     with ONE per-page absmax scale — the rounding happens *before* the
     W_uk/W_uv expansion, a different error model from the GQA tier (see
-    ``runtime/kv_quant.py``)."""
+    ``runtime/kv_quant.py``).
+
+    Each K/V or latent row is stored ``kv_cache.row_width`` wide: padded
+    with zero lanes to a multiple of 128 on a TPU, so the pools keep the
+    row-major layout the flash kernels read."""
+    from repro.runtime.kv_cache import row_width
     if kv_dtype not in (None, 'fp', 'int8'):
         raise ValueError(f'kv_dtype must be None/"fp"/"int8", got {kv_dtype!r}')
     tiered = kv_dtype == 'int8'
@@ -127,7 +134,7 @@ def init_paged_cache(cfg, batch: int, *, num_pages: int, page_size: int,
                          'is always full-precision')
     if cfg.mla is not None:
         m = cfg.mla
-        dk = m.kv_lora_rank + m.rope_head_dim
+        dk = row_width(m.kv_lora_rank + m.rope_head_dim)
         cache = dict(
             cl=jnp.zeros((num_pages, page_size, dk), dtype),
             bt=jnp.zeros((batch, max_blocks), jnp.int32),
@@ -139,7 +146,7 @@ def init_paged_cache(cfg, batch: int, *, num_pages: int, page_size: int,
                 hw=jnp.full((1,), hot_window, jnp.int32),
             )
         return cache
-    hkv, dh = cfg.n_kv_heads, cfg.resolved_head_dim
+    hkv, dh = cfg.n_kv_heads, row_width(cfg.resolved_head_dim)
     cache = dict(
         k=jnp.zeros((num_pages, page_size, hkv, dh), dtype),
         v=jnp.zeros((num_pages, page_size, hkv, dh), dtype),
@@ -307,10 +314,12 @@ def attention(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
               theta: Optional[float] = None,
               cache: Optional[dict] = None,
               cache_pos: Optional[jnp.ndarray] = None,
-              rt=None,
+              rt=None, layer=None,
               ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Full-sequence attention (train) or prefill (``cache`` given: KV written
-    at [0, s)). Returns (out, updated_cache)."""
+    at [0, s)). Returns (out, updated_cache). ``layer`` (int32 scalar):
+    ``cache`` is the layer-stacked node, written at that layer in place
+    (the same contract on every decode/chunk entry point below)."""
     b, s, _ = x.shape
     dh = cfg.resolved_head_dim
     theta = theta if theta is not None else cfg.rope_theta
@@ -324,7 +333,7 @@ def attention(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
         # quantizes aged-out pages after admission; tier leaves pass
         # through untouched (the layout owns that discipline)
         new_cache = layouts.get_layout(cache).write_prefill(
-            cache, dict(k=k, v=v))
+            cache, dict(k=k, v=v), layer=layer)
     mask = causal_mask(s, s, 0, window)
     out = _sdpa(q, k, v, mask, 1.0 / jnp.sqrt(dh).astype(jnp.float32))
     out = _tp_heads_gather(out.reshape(b, s, -1), rt)
@@ -336,7 +345,7 @@ def attention_decode(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
                      cache: dict, pos: jnp.ndarray,
                      window: Optional[int] = None,
                      theta: Optional[float] = None,
-                     rt=None,
+                     rt=None, layer=None,
                      ) -> Tuple[jnp.ndarray, dict]:
     """One-token decode. x: (B, 1, d); ``pos``: scalar int or (B,) vector of
     per-request absolute positions being generated; cache holds [0, pos)
@@ -361,13 +370,13 @@ def attention_decode(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
     posr = (jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
             if layout.paged else pos)
     # writes always land in the fp (hot-tier) pools, quantized or not
-    new_cache = layout.write_token(cache, dict(k=k, v=v), posr)
+    new_cache = layout.write_token(cache, dict(k=k, v=v), posr, layer=layer)
     if use_flash:
         out = layout.flash_decode(q, new_cache, posr, scale=scale,
-                                  window=window)
+                                  window=window, layer=layer)
     else:
         # einsum oracle on the layout's densified (tier-mixing) view
-        kd, vd = layout.gather(new_cache, posr)
+        kd, vd = layout.gather(new_cache, posr, layer=layer, width=dh)
         out = sdpa_decode(q, kd, vd, posr, scale, window)
     out = _tp_heads_gather(out.reshape(b, 1, -1), rt)
     out = yoco_linear.linear(out, p['wo'], cfg=yoco)
@@ -378,7 +387,7 @@ def attention_chunk(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
                     cache: dict, offset: jnp.ndarray, limit: jnp.ndarray,
                     window: Optional[int] = None,
                     theta: Optional[float] = None,
-                    rt=None,
+                    rt=None, layer=None,
                     ) -> Tuple[jnp.ndarray, dict]:
     """Chunked prefill: C tokens of a longer prompt, at absolute positions
     offset[b] .. offset[b] + C - 1, attending everything already written
@@ -396,14 +405,15 @@ def attention_chunk(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
     scale = 1.0 / float(dh) ** 0.5
     from repro.runtime import layouts
     layout = layouts.get_layout(cache)
-    new_cache = layout.write_chunk(cache, dict(k=k, v=v), offset, limit)
+    new_cache = layout.write_chunk(cache, dict(k=k, v=v), offset, limit,
+                                   layer=layer)
     use_flash = (rt is not None
                  and getattr(rt, 'attn_impl', 'einsum') == 'flash')
     if use_flash:
         out = layout.flash_chunk(q, new_cache, offset, limit, scale=scale,
-                                 window=window)
+                                 window=window, layer=layer)
     else:
-        kd, vd = layout.gather_fp(new_cache)
+        kd, vd = layout.gather_fp(new_cache, layer=layer, width=dh)
         mask = chunk_mask(offset, c, kd.shape[1], window)
         out = _sdpa(q, kd, vd, mask[:, None, None, :, :], scale)
     out = _tp_heads_gather(out.reshape(b, c, -1), rt)
@@ -440,7 +450,7 @@ def _mla_qkv_full(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig,
 def mla_attention(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
                   positions: Optional[jnp.ndarray] = None,
                   cache: Optional[dict] = None,
-                  rt=None,
+                  rt=None, layer=None,
                   ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """MLA train / prefill (materializes per-head k/v; caches only latents).
 
@@ -483,7 +493,7 @@ def mla_attention(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
         # paged latent layouts scatter ckv ‖ krope as ONE row per token;
         # the registry owns that concatenation discipline
         new_cache = layouts.get_layout(cache).write_prefill(
-            cache, dict(ckv=ckv, krope=krope))
+            cache, dict(ckv=ckv, krope=krope), layer=layer)
     scale = 1.0 / jnp.sqrt(float(m.nope_head_dim + m.rope_head_dim))
     mask = causal_mask(s, s)
     lo = jnp.einsum('bqhd,bshd->bhqs', q_nope, k_nope,
@@ -565,6 +575,7 @@ def mla_absorbed_attend(q_lat: jnp.ndarray, q_rope: jnp.ndarray,
 
 def mla_attention_decode(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
                          cache: dict, pos: jnp.ndarray, rt=None,
+                         layer=None,
                          ) -> Tuple[jnp.ndarray, dict]:
     """Absorbed MLA decode: attention runs in the latent space.
 
@@ -623,15 +634,16 @@ def mla_attention_decode(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
             if layout.paged else pos)
     # writes always land in the fp latent pool, quantized layout or not
     new_cache = layout.write_token(cache, dict(ckv=ckv_t, krope=krope_t),
-                                   posr)
+                                   posr, layer=layer)
     if use_flash and layout.paged:
         o_lat = layout.flash_decode(
             jnp.concatenate([q_lat, q_rope.astype(jnp.float32)], -1),
-            new_cache, posr, scale=scale, r=r)
+            new_cache, posr, scale=scale, r=r, layer=layer)
     else:
         # absorbed einsum oracle on the layout's densified (tier-mixing)
         # latent view (the MLA flash kernels are paged-only)
-        ckv_d, krope_d = layout.gather(new_cache, posr, r=r)
+        ckv_d, krope_d = layout.gather(new_cache, posr, r=r, layer=layer,
+                                       width=r + m.rope_head_dim)
         o_lat = mla_absorbed_attend(q_lat, q_rope, ckv_d, krope_d, posr,
                                     scale)
 
@@ -643,7 +655,7 @@ def mla_attention_decode(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
 
 def mla_attention_chunk(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
                         cache: dict, offset: jnp.ndarray,
-                        limit: jnp.ndarray, rt=None,
+                        limit: jnp.ndarray, rt=None, layer=None,
                         ) -> Tuple[jnp.ndarray, dict]:
     """Chunked MLA prefill through the absorbed decode math: C tokens at
     absolute positions offset[b] .. offset[b] + C - 1 attend the paged
@@ -678,15 +690,16 @@ def mla_attention_chunk(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
     layout = layouts.get_layout(cache)
     r = m.kv_lora_rank
     new_cache = layout.write_chunk(cache, dict(ckv=ckv_t, krope=krope_t),
-                                   offset, limit)
+                                   offset, limit, layer=layer)
     use_flash = (rt is not None
                  and getattr(rt, 'attn_impl', 'einsum') == 'flash')
     if use_flash and layout.paged:
         o_lat = layout.flash_chunk(
             jnp.concatenate([q_lat, q_rope.astype(jnp.float32)], -1),
-            new_cache, offset, limit, scale=scale, r=r)
+            new_cache, offset, limit, scale=scale, r=r, layer=layer)
     else:
-        ckv_d, krope_d = layout.gather_fp(new_cache, r=r)
+        ckv_d, krope_d = layout.gather_fp(new_cache, r=r, layer=layer,
+                                          width=r + m.rope_head_dim)
         lo = jnp.einsum('bqhr,bsr->bhqs', q_lat,
                         ckv_d.astype(jnp.float32))
         lo += jnp.einsum('bqhd,bsd->bhqs', q_rope.astype(jnp.float32),

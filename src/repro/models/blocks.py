@@ -38,46 +38,49 @@ def init_transformer_block(key: jax.Array, cfg, *, use_moe: bool) -> dict:
 
 
 def _mix_attn(p, x, cfg, yoco, *, window, theta, cache, cache_pos,
-              decode_pos, rt=None, chunk_ctx=None):
+              decode_pos, rt=None, chunk_ctx=None, layer=None):
     if chunk_ctx is not None:
         if cfg.mla is not None:
             return attn_mod.mla_attention_chunk(
                 p['attn'], x, cfg, yoco, cache=cache,
-                offset=chunk_ctx['offset'], limit=chunk_ctx['limit'], rt=rt)
+                offset=chunk_ctx['offset'], limit=chunk_ctx['limit'], rt=rt,
+                layer=layer)
         return attn_mod.attention_chunk(
             p['attn'], x, cfg, yoco, cache=cache,
             offset=chunk_ctx['offset'], limit=chunk_ctx['limit'],
-            window=window, theta=theta, rt=rt)
+            window=window, theta=theta, rt=rt, layer=layer)
     if cfg.mla is not None:
         if decode_pos is not None:
             return attn_mod.mla_attention_decode(p['attn'], x, cfg, yoco,
                                                  cache=cache, pos=decode_pos,
-                                                 rt=rt)
+                                                 rt=rt, layer=layer)
         return attn_mod.mla_attention(p['attn'], x, cfg, yoco, cache=cache,
-                                      rt=rt)
+                                      rt=rt, layer=layer)
     if decode_pos is not None:
         return attn_mod.attention_decode(p['attn'], x, cfg, yoco, cache=cache,
                                          pos=decode_pos, window=window,
-                                         theta=theta, rt=rt)
+                                         theta=theta, rt=rt, layer=layer)
     return attn_mod.attention(p['attn'], x, cfg, yoco, window=window,
                               theta=theta, cache=cache, cache_pos=cache_pos,
-                              rt=rt)
+                              rt=rt, layer=layer)
 
 
 def transformer_block(p: dict, x: jnp.ndarray, cfg, yoco: YocoConfig, *,
                       window=None, theta=None,
                       cache: Optional[dict] = None,
                       cache_pos=None, decode_pos=None,
-                      moe_ctx=None, rt=None, chunk_ctx=None
+                      moe_ctx=None, rt=None, chunk_ctx=None, layer=None
                       ) -> Tuple[jnp.ndarray, Optional[dict], dict]:
     """Pre-norm residual block. Returns (x, new_cache, metrics).
     ``chunk_ctx`` (dict(offset=, limit=), both (B,) int32) routes the
-    attention mix through the chunked-prefill path instead."""
+    attention mix through the chunked-prefill path instead. ``layer``
+    (int32 scalar): ``cache`` is the whole layer-stacked cache node and
+    this block reads and writes its layer ``layer`` in place."""
     h = apply_norm(p['attn_norm'], x, cfg)
     a, new_cache = _mix_attn(p, h, cfg, yoco, window=window, theta=theta,
                              cache=cache, cache_pos=cache_pos,
                              decode_pos=decode_pos, rt=rt,
-                             chunk_ctx=chunk_ctx)
+                             chunk_ctx=chunk_ctx, layer=layer)
     if 'post_attn_norm' in p:
         a = apply_norm(p['post_attn_norm'], a, cfg)
     x = x + a

@@ -11,7 +11,9 @@
 Layer stacks are scanned (``jax.lax.scan`` over stacked params) so the HLO
 stays compact at 61-80 layers; heterogeneity (gemma3 local/global pattern,
 deepseek dense-prefix) is expressed as per-layer scan inputs or separate
-stacks. Optional remat wraps the scan body (``rt.remat``).
+stacks. A serving cache rides in the scan carry and each layer indexes
+it in place (see ``_transformer_stack``). Optional remat wraps the scan
+body (``rt.remat``).
 """
 
 from __future__ import annotations
@@ -217,34 +219,43 @@ def _head(params: dict, x: jnp.ndarray, cfg, yoco: YocoConfig) -> jnp.ndarray:
 def _transformer_stack(stack: dict, x: jnp.ndarray, cfg, yoco, rt, *,
                        cache: Optional[dict], decode_pos, use_moe: bool,
                        chunk_ctx=None):
-    """Scan a homogeneous transformer stack. cache: stacked (L, ...) or None.
-    Returns (x, new_cache, aux_sum)."""
+    """Scan a homogeneous transformer stack. Returns (x, new_cache,
+    aux_sum).
+
+    ``cache`` (every leaf stacked (L, ...), or None for training) rides in
+    the scan CARRY, whole: the scan runs over (params, layer index) and
+    each layer's block reads and writes its own layer of the stacked
+    pools in place — writes at ``[l, page, row]``, the flash kernels DMA
+    layer ``l``'s live pages straight out of the stack, the small
+    ``bt``/``hw`` copies are read at ``[l]``. Slicing the pools as scan
+    inputs and restacking them as scan outputs would instead copy every
+    layer's pool out and back, and the whole stack once more, on every
+    step program."""
     gemma = cfg.local_global_every > 0
+    n = jax.tree.leaves(stack)[0].shape[0]
+    window = theta = None
     if gemma:
         window, theta = _gemma_layer_meta(cfg)
-        n = jax.tree.leaves(stack)[0].shape[0]
         window, theta = window[:n], theta[:n]
     moe_ctx = rt.moe_ctx
 
     def body(carry, xs):
-        h, aux = carry
-        if gemma:
-            lp, win, th, lc = xs
-        else:
-            lp, lc = xs
+        h, aux, c = carry
+        lp, layer, win, th = xs
+        if not gemma:
             win = cfg.sliding_window
-            th = None
-        h, new_lc, metrics = blk.transformer_block(
-            lp, h, cfg, yoco, window=win, theta=th, cache=lc,
+        h, c, metrics = blk.transformer_block(
+            lp, h, cfg, yoco, window=win, theta=th, cache=c,
             decode_pos=decode_pos, moe_ctx=moe_ctx, rt=rt,
-            chunk_ctx=chunk_ctx)
+            chunk_ctx=chunk_ctx, layer=layer)
         h = _constrain(h, rt)
         aux = aux + (metrics.get('aux_loss', 0.0) if use_moe else 0.0)
-        return (h, aux), new_lc
+        return (h, aux, c), None
 
     body = _maybe_remat(body, rt)
-    xs = ((stack, window, theta, cache) if gemma else (stack, cache))
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
+    layers = None if cache is None else jnp.arange(n, dtype=jnp.int32)
+    (x, aux, new_cache), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0), cache), (stack, layers, window, theta))
     return x, new_cache, aux
 
 
@@ -387,15 +398,17 @@ def init_paged_cache_tree(cfg, batch: int, *, num_pages: int,
                           kv_dtype: Optional[str] = None,
                           hot_window: int = 1) -> dict:
     """Paged-cache analogue of :func:`init_cache_tree`: each attention
-    layer gets its own physical pool (stacked over L), every layer shares
-    the same logical block tables (the ``bt`` leaf is broadcast per layer so
-    the layer scan slices it for free; ``runtime.kv_cache.with_block_tables``
+    layer gets its own physical pool, stacked over L into one buffer per
+    leaf that the layer scan carries whole and indexes by layer in place
+    (see :func:`_transformer_stack`). Every layer shares the same logical
+    block tables: the ``bt`` leaf keeps one small copy per layer, read at
+    the scan's layer index (``runtime.kv_cache.with_block_tables``
     refreshes every copy when the scheduler reassigns pages).
 
     ``kv_dtype='int8'`` builds the hybrid-precision tier layouts
     (``runtime.layouts.PagedQ8Layout`` / ``PagedMLAQ8Layout``): per-layer
-    int8 pools + scale leaves and the per-layer-broadcast ``hw``
-    hot-window knob, alongside the fp pools. MLA configs get one latent
+    int8 pools + scale leaves and the per-layer ``hw`` hot-window knob,
+    alongside the fp pools. MLA configs get one latent
     ``cl`` pool per layer instead of k/v pairs; their int8 tier quantizes
     the latent per-page absmax before the W_uk/W_uv expansion.
 

@@ -14,8 +14,9 @@ The pure pool ops below are generic over the per-position payload — they
 only index ``(page, row)`` and carry whatever trailing dims the pool has.
 GQA pools are ``(P, page_size, Hkv, dh)``; MLA latent pools are
 ``(P, page_size, r + d_rope)`` (one row = one token's concatenated
-``ckv``/``krope`` latent, consumed by ``flash_decode_paged_mla``). The
-allocator never sees the payload shape at all.
+``ckv``/``krope`` latent, consumed by ``flash_decode_paged_mla``). On a
+TPU the last dim is stored padded to whole lanes (:func:`row_width`);
+writes pad to it. The allocator never sees the payload shape at all.
 
 Page 0 is the reserved *garbage page*: it is never allocated, idle slots'
 block tables point at it (all-zero rows), and clamped out-of-range writes
@@ -555,34 +556,81 @@ class PagedKVCache:
 # ----------------------------------------------------------------------------
 # pure device-side ops (jittable; live inside the serve steps)
 # ----------------------------------------------------------------------------
+LANES = 128                 # a TPU vector register's lanes
+
+
+def _lanes() -> int:
+    """The lane count rows pad to: a TPU's 128, else 1 (no padding)."""
+    return LANES if jax.default_backend() == 'tpu' else 1
+
+
+def row_width(d: int) -> int:
+    """Stored width of a K/V (or latent) row of ``d`` values: ``d`` rounded
+    up to whole lanes on a TPU, ``d`` elsewhere. The chip's default layout
+    keeps a pool whose minor dim is a multiple of 128 row-major, which is
+    the layout a Pallas operand must have; for a narrower row (head dim
+    64 or 160, latent 576) it puts the page axis minor-most instead, and
+    every step program would re-lay the whole pool out around the flash
+    kernels. The padding lanes hold zeros: writes pad (:func:`fit_row`);
+    ``runtime.layouts`` pads the kernels' queries and cuts the padding
+    off their outputs and off the densify oracles' views."""
+    return -(-d // _lanes()) * _lanes()
+
+
+def pad_row(t: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``t`` with its last dim zero-padded to ``width``."""
+    pad = width - t.shape[-1]
+    if pad:
+        t = jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, pad)])
+    return t
+
+
+def fit_row(t: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
+    """``t`` in ``c``'s dtype with its last dim zero-padded to ``c``'s
+    (the stored row width of :func:`row_width`)."""
+    return pad_row(t.astype(c.dtype), c.shape[-1])
+
+
+def _page_rows(pool: jnp.ndarray, layer):
+    """(page size, index prefix) of a pool: (P, page_size, ...) alone, or
+    the layer-stacked (L, P, page_size, ...) written at ``layer`` in place
+    (every write op below indexes ``prefix + (page, row)``)."""
+    if layer is None:
+        return pool.shape[1], ()
+    return pool.shape[2], (layer,)
+
+
 def paged_token_update(pool: jnp.ndarray, t: jnp.ndarray, pos: jnp.ndarray,
-                       block_tables: jnp.ndarray) -> jnp.ndarray:
+                       block_tables: jnp.ndarray, layer=None) -> jnp.ndarray:
     """Write one decode-step K/V slab into the paged pool.
 
-    pool: (P, page_size, ...); t: (B, 1, ...); pos: (B,) int32;
+    pool: (P, page_size, ...), or layer-stacked (L, P, page_size, ...)
+    written at int32 ``layer``; t: (B, 1, ...); pos: (B,) int32;
     block_tables: (B, W). Trailing dims are opaque ((Hkv, dh) for GQA,
     (r + d_rope,) for the MLA latent pool). Returns the updated pool.
     Slots whose table rows are all GARBAGE_PAGE write into page 0 (masked
     on read)."""
-    ps = pool.shape[1]
+    ps, lead = _page_rows(pool, layer)
     pos = jnp.asarray(pos, jnp.int32).reshape(-1)
     blk = pos // ps
     page = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-    return pool.at[page, pos % ps].set(t[:, 0].astype(pool.dtype))
+    return pool.at[lead + (page, pos % ps)].set(fit_row(t[:, 0], pool))
 
 
 def paged_prefill_update(pool: jnp.ndarray, t: jnp.ndarray,
-                         block_tables: jnp.ndarray) -> jnp.ndarray:
+                         block_tables: jnp.ndarray,
+                         layer=None) -> jnp.ndarray:
     """Write a whole prompt's K/V rows into the paged pool.
 
-    pool: (P, page_size, ...); t: (B, Sp, ...) (trailing dims opaque, same
-    as :func:`paged_token_update`);
-    block_tables: (B, W) with W * page_size >= Sp. Row l of request b goes
-    to page block_tables[b, l // page_size] — allocate ceil(Sp/page_size)
-    blocks before prefilling (padded tail rows land in owned pages and are
-    overwritten as the request advances, same as the contiguous layout)."""
+    pool: (P, page_size, ...), or layer-stacked and written at ``layer``
+    (same as :func:`paged_token_update`); t: (B, Sp, ...) (trailing dims
+    opaque); block_tables: (B, W) with W * page_size >= Sp. Row l of
+    request b goes to page block_tables[b, l // page_size] — allocate
+    ceil(Sp/page_size) blocks before prefilling (padded tail rows land in
+    owned pages and are overwritten as the request advances, same as the
+    contiguous layout)."""
     b, sp = t.shape[:2]
-    ps = pool.shape[1]
+    ps, lead = _page_rows(pool, layer)
     if sp > block_tables.shape[1] * ps:
         # a prompt the table can't hold must fail loudly at trace time —
         # the scatter below would otherwise clamp/wrap rows silently
@@ -593,23 +641,25 @@ def paged_prefill_update(pool: jnp.ndarray, t: jnp.ndarray,
     l = jnp.arange(sp, dtype=jnp.int32)
     page = block_tables[:, l // ps]                        # (B, Sp)
     row = jnp.broadcast_to(l % ps, (b, sp))
-    return pool.at[page.reshape(-1), row.reshape(-1)].set(
-        t.reshape(b * sp, *t.shape[2:]).astype(pool.dtype))
+    return pool.at[lead + (page.reshape(-1), row.reshape(-1))].set(
+        fit_row(t.reshape(b * sp, *t.shape[2:]), pool))
 
 
 def paged_chunk_update(pool: jnp.ndarray, t: jnp.ndarray, offset, limit,
-                       block_tables: jnp.ndarray) -> jnp.ndarray:
+                       block_tables: jnp.ndarray, layer=None) -> jnp.ndarray:
     """Write a prefill CHUNK's K/V rows into the paged pool.
 
-    pool: (P, page_size, ...); t: (B, C, ...) — row i of request b holds
-    absolute position ``offset[b] + i``; offset/limit: scalar or (B,)
-    int32; block_tables: (B, W). Rows at or beyond ``limit[b]`` (the
-    chunk's padded tail) and rows past the table capacity are redirected
-    onto the garbage page, so — unlike :func:`paged_prefill_update` —
-    padding NEVER lands in an owned page (the shared-prefix stale-data
-    guard) and the update stays shape-static under jit."""
+    pool: (P, page_size, ...), or layer-stacked and written at ``layer``
+    (same as :func:`paged_token_update`); t: (B, C, ...) — row i of
+    request b holds absolute position ``offset[b] + i``; offset/limit:
+    scalar or (B,) int32; block_tables: (B, W). Rows at or beyond
+    ``limit[b]`` (the chunk's padded tail) and rows past the table
+    capacity are redirected onto the garbage page, so — unlike
+    :func:`paged_prefill_update` — padding NEVER lands in an owned page
+    (the shared-prefix stale-data guard) and the update stays
+    shape-static under jit."""
     b, c = t.shape[:2]
-    ps = pool.shape[1]
+    ps, lead = _page_rows(pool, layer)
     w = block_tables.shape[1]
     off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32).reshape(-1), (b,))
     lim = jnp.broadcast_to(jnp.asarray(limit, jnp.int32).reshape(-1), (b,))
@@ -619,8 +669,8 @@ def paged_chunk_update(pool: jnp.ndarray, t: jnp.ndarray, offset, limit,
     page = jnp.take_along_axis(block_tables, blk, axis=1)
     page = jnp.where(ok, page, GARBAGE_PAGE)
     row = posl % ps
-    return pool.at[page.reshape(-1), row.reshape(-1)].set(
-        t.reshape(b * c, *t.shape[2:]).astype(pool.dtype))
+    return pool.at[lead + (page.reshape(-1), row.reshape(-1))].set(
+        fit_row(t.reshape(b * c, *t.shape[2:]), pool))
 
 
 def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:

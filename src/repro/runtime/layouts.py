@@ -26,6 +26,15 @@ walk a (possibly layer-stacked) cache tree, classify each dict node, and
 apply the matched layout's op — ``runtime.kv_cache`` and
 ``runtime.kv_quant`` re-export the first two under their historical names.
 
+Inside the layer scan every write, densify and kernel op takes the whole
+layer-stacked dict plus a ``layer`` index and works on that layer in
+place (:class:`CacheLayout`). On a TPU the K/V and latent rows are
+stored padded to whole lanes (``kv_cache.row_width``), so that the pools
+keep the row-major layout the kernels read and no step program copies
+one. The padding stays behind the layouts: writes pad each row, the
+kernel entrypoints pad the query and cut the padding off the output, and
+the densify oracles return the rows at their logical ``width``.
+
 Layout schemas (single layer; layer stacks prepend an (L,) dim to every
 leaf):
 
@@ -105,19 +114,36 @@ def match_layout(cache: dict) -> Optional[Type['CacheLayout']]:
     return None
 
 
-def dense_token_update(c: jnp.ndarray, t: jnp.ndarray, pos) -> jnp.ndarray:
+def dense_token_update(c: jnp.ndarray, t: jnp.ndarray, pos,
+                       layer=None) -> jnp.ndarray:
     """Write the step's slab ``t`` (B, 1, ...) into a contiguous cache
     ``c`` (B, S_max, ...) at absolute position ``pos`` (scalar, or (B,)
-    for heterogeneous-position batches)."""
-    t = t.astype(c.dtype)
+    for heterogeneous-position batches). With an int32 ``layer``, ``c``
+    is the layer-stacked (L, B, S_max, ...) cache, written at that layer
+    in place."""
+    t = kvc.fit_row(t, c)
+    lead = () if layer is None else (layer,)
+    t = t[(None,) * len(lead)]
     if jnp.ndim(pos) == 0:
         return jax.lax.dynamic_update_slice(
-            c, t, (0, pos) + (0,) * (c.ndim - 2))
+            c, t, lead + (0, pos) + (0,) * (c.ndim - 2 - len(lead)))
 
     def one(cb, tb, pb):
         return jax.lax.dynamic_update_slice(
-            cb, tb, (pb,) + (0,) * (cb.ndim - 1))
-    return jax.vmap(one)(c, t, jnp.asarray(pos, jnp.int32))
+            cb, tb, lead + (pb,) + (0,) * (cb.ndim - 1 - len(lead)))
+    ax = len(lead)
+    return jax.vmap(one, in_axes=(ax, ax, 0), out_axes=ax)(
+        c, t, jnp.asarray(pos, jnp.int32))
+
+
+def _dense_prefill(c: jnp.ndarray, t: jnp.ndarray, layer) -> jnp.ndarray:
+    """Write a prompt slab ``t`` (B, S, ...) at position 0 of a contiguous
+    cache (at ``layer`` of a layer-stacked one, in place)."""
+    t = kvc.fit_row(t, c)
+    if layer is None:
+        return jax.lax.dynamic_update_slice(c, t, (0,) * c.ndim)
+    return jax.lax.dynamic_update_slice(c, t[None],
+                                        (layer,) + (0,) * (c.ndim - 1))
 
 
 def _pos_vec(pos, b: int) -> jnp.ndarray:
@@ -130,13 +156,52 @@ def _latent_row(updates: dict) -> jnp.ndarray:
     return jnp.concatenate([updates['ckv'], updates['krope']], axis=-1)
 
 
+def _at(leaf: jnp.ndarray, layer) -> jnp.ndarray:
+    """Layer ``layer``'s copy of a small per-layer leaf (block table, hot
+    window) — the leaf itself for a single-layer cache dict."""
+    return leaf if layer is None else leaf[layer]
+
+
+def _rows(a: jnp.ndarray, width: Optional[int]) -> jnp.ndarray:
+    """A densified view less the rows' storage padding past the logical
+    ``width`` (``kv_cache.row_width``); ``a`` itself for ``width=None``."""
+    return a if width is None else a[..., :width]
+
+
+def _padded_query(q: jnp.ndarray, pool: jnp.ndarray) -> jnp.ndarray:
+    """The query zero-padded to the pool's stored row width: the padding
+    lanes of the pool hold zeros, and zero query lanes add nothing to any
+    score."""
+    return kvc.pad_row(q, pool.shape[-1])
+
+
+def _layer_view(cache: dict, layer) -> dict:
+    """One layer's slice of every leaf. Only the einsum oracle's densify
+    path takes it; writes and kernels index the stacked pools in place."""
+    if layer is None:
+        return cache
+    return {k: v[layer] for k, v in cache.items()}
+
+
 # ----------------------------------------------------------------------------
 # the layouts
 # ----------------------------------------------------------------------------
 class CacheLayout:
     """One cache layout: leaf schema + write ops + densify oracle + kernel
     entrypoint. All methods are classmethods over plain cache dicts — the
-    layout carries no instance state (the cache dict IS the state)."""
+    layout carries no instance state (the cache dict IS the state).
+
+    Every write, densify and kernel method takes an optional ``layer``
+    (int32 scalar, traced inside the layer scan): the cache dict is then
+    the whole layer-stacked node (every leaf has a leading (L,) dim) and
+    the op reads or writes layer ``layer`` of it in place — pools at
+    ``[layer, page, row]``, the small ``bt``/``hw`` copies at ``[layer]``.
+    ``layer=None`` is the single-layer dict.
+
+    The stored rows may be wider than the model's (``kv_cache.row_width``);
+    the kernel entrypoints take and return the model's widths, and the
+    densify oracles cut their views at ``width`` (the model's row: ``dh``,
+    or ``r + d_rope`` for a latent row)."""
 
     name: str = ''
     required: frozenset = frozenset()   # leaf schema that identifies it
@@ -177,15 +242,17 @@ class CacheLayout:
 
     # -- write ops ----------------------------------------------------------
     @classmethod
-    def write_token(cls, cache: dict, updates: dict, pos) -> dict:
+    def write_token(cls, cache: dict, updates: dict, pos,
+                    layer=None) -> dict:
         raise NotImplementedError
 
     @classmethod
-    def write_prefill(cls, cache: dict, updates: dict) -> dict:
+    def write_prefill(cls, cache: dict, updates: dict, layer=None) -> dict:
         raise NotImplementedError
 
     @classmethod
-    def write_chunk(cls, cache: dict, updates: dict, offset, limit) -> dict:
+    def write_chunk(cls, cache: dict, updates: dict, offset, limit,
+                    layer=None) -> dict:
         """Write a prefill chunk's rows at absolute positions
         [offset, offset + C); rows at or beyond ``limit`` clamp onto the
         garbage page (chunked prefill never writes padding into owned —
@@ -194,7 +261,8 @@ class CacheLayout:
 
     # -- densify oracle / kernel entrypoint ---------------------------------
     @classmethod
-    def gather(cls, cache: dict, pos, r: Optional[int] = None):
+    def gather(cls, cache: dict, pos, r: Optional[int] = None, layer=None,
+               width: Optional[int] = None):
         """Contiguous views for the einsum oracle: (k, v) for K/V layouts,
         (ckv, krope) for MLA layouts (``r`` is the static latent rank the
         paged latent pool splits at). ``pos`` only matters to the tiered
@@ -202,25 +270,27 @@ class CacheLayout:
         raise NotImplementedError
 
     @classmethod
-    def gather_fp(cls, cache: dict, r: Optional[int] = None):
+    def gather_fp(cls, cache: dict, r: Optional[int] = None, layer=None,
+                  width: Optional[int] = None):
         """Full-precision-pool densify, ignoring any int8 tier — the
         chunked-prefill read path: pages written moments ago by earlier
         chunks are not quantized yet, so tier mixing would read zeros.
         The fp pools always hold the authoritative content (quantization
         copies, never moves). Defaults to :meth:`gather` for layouts
         without a tier."""
-        return cls.gather(cache, 0, r=r)
+        return cls.gather(cache, 0, r=r, layer=layer, width=width)
 
     @classmethod
     def flash_decode(cls, q, cache: dict, pos, *, scale, window=None,
-                     interpret=None, r: Optional[int] = None):
+                     interpret=None, r: Optional[int] = None, layer=None):
         """Route the decode read through this layout's Pallas kernel
         (``r`` is the static latent rank, MLA layouts only)."""
         raise NotImplementedError
 
     @classmethod
     def flash_chunk(cls, q, cache: dict, offset, limit, *, scale,
-                    window=None, interpret=None, r: Optional[int] = None):
+                    window=None, interpret=None, r: Optional[int] = None,
+                    layer=None):
         """Route a chunked-prefill read (q_len > 1) through the paged
         flash kernel. Reads the fp pools only (same rationale as
         :meth:`gather_fp`). Paged layouts only."""
@@ -246,18 +316,60 @@ class CacheLayout:
         raise NotImplementedError(f'{cls.name} carries no per-slot state')
 
 
+class _PagedMLABase(CacheLayout):
+    """Write ops and fp-pool reads shared by the two paged latent
+    layouts: every write lands in the fp ``cl`` pool."""
+    paged = True
+    mla = True
+    table_leaves = ('bt',)
+
+    @classmethod
+    def write_token(cls, cache, updates, pos, layer=None):
+        lat = _latent_row(updates)
+        posv = _pos_vec(pos, lat.shape[0])
+        return dict(cache, cl=kvc.paged_token_update(
+            cache['cl'], lat, posv, _at(cache['bt'], layer), layer))
+
+    @classmethod
+    def write_prefill(cls, cache, updates, layer=None):
+        return dict(cache, cl=kvc.paged_prefill_update(
+            cache['cl'], _latent_row(updates), _at(cache['bt'], layer),
+            layer))
+
+    @classmethod
+    def write_chunk(cls, cache, updates, offset, limit, layer=None):
+        return dict(cache, cl=kvc.paged_chunk_update(
+            cache['cl'], _latent_row(updates), offset, limit,
+            _at(cache['bt'], layer), layer))
+
+    @classmethod
+    def gather_fp(cls, cache, r=None, layer=None, width=None):
+        assert r is not None, 'MLA gathers need the static latent rank r'
+        c = _layer_view(cache, layer)
+        dense = _rows(kvc.gather_pages(c['cl'], c['bt']), width)
+        return dense[..., :r], dense[..., r:]
+
+    @classmethod
+    def flash_chunk(cls, q, cache, offset, limit, *, scale, window=None,
+                    interpret=None, r=None, layer=None):
+        # fp pool only: earlier chunks' pages are not quantized yet
+        from repro.kernels import flash_decode as fd
+        return fd.flash_chunk_paged_mla(_padded_query(q, cache['cl']),
+                                        cache['cl'], offset, limit,
+                                        _at(cache['bt'], layer), r=r,
+                                        scale=scale, window=window,
+                                        interpret=interpret, layer=layer)
+
+
 @_register
-class PagedMLAQ8Layout(CacheLayout):
+class PagedMLAQ8Layout(_PagedMLABase):
     """Paged MLA latent pool + int8 cold tier: ``cl``/``clq``/``cs``/
     ``bt``/``hw``. Writes land in the fp ``cl`` pool; aged-out pages are
     quantized per-page absmax *before* the W_uk/W_uv expansion (see
     ``runtime.kv_quant`` for the error model)."""
     name = 'paged_mla_q8'
     required = frozenset({'cl', 'clq', 'cs', 'bt', 'hw'})
-    paged = True
     quantized = True
-    mla = True
-    table_leaves = ('bt',)
     quant_leaves = ('cl', 'clq', 'cs')
     quant_probe = 'cs'
     quant_probe_ndim = 2
@@ -265,52 +377,22 @@ class PagedMLAQ8Layout(CacheLayout):
     poison_leaves = ('cl',)
 
     @classmethod
-    def write_token(cls, cache, updates, pos):
-        lat = _latent_row(updates)
-        posv = _pos_vec(pos, lat.shape[0])
-        return dict(cache, cl=kvc.paged_token_update(cache['cl'], lat, posv,
-                                                     cache['bt']))
-
-    @classmethod
-    def write_prefill(cls, cache, updates):
-        return dict(cache, cl=kvc.paged_prefill_update(
-            cache['cl'], _latent_row(updates), cache['bt']))
-
-    @classmethod
-    def write_chunk(cls, cache, updates, offset, limit):
-        return dict(cache, cl=kvc.paged_chunk_update(
-            cache['cl'], _latent_row(updates), offset, limit, cache['bt']))
-
-    @classmethod
-    def gather(cls, cache, pos, r=None):
+    def gather(cls, cache, pos, r=None, layer=None, width=None):
         assert r is not None, 'MLA gathers need the static latent rank r'
-        dense = kvq.dequant_gather_mla(
-            cache, _pos_vec(pos, cache['bt'].shape[0]))
-        return dense[..., :r], dense[..., r:]
-
-    @classmethod
-    def gather_fp(cls, cache, r=None):
-        assert r is not None, 'MLA gathers need the static latent rank r'
-        dense = kvc.gather_pages(cache['cl'], cache['bt'])
+        c = _layer_view(cache, layer)
+        dense = _rows(kvq.dequant_gather_mla(
+            c, _pos_vec(pos, c['bt'].shape[0])), width)
         return dense[..., :r], dense[..., r:]
 
     @classmethod
     def flash_decode(cls, q, cache, pos, *, scale, window=None,
-                     interpret=None, r=None):
+                     interpret=None, r=None, layer=None):
         from repro.kernels import flash_decode as fd
         return fd.flash_decode_paged_mla_q8(
-            q, cache['cl'], cache['clq'], cache['cs'], pos, cache['bt'],
-            cache['hw'], r=r, scale=scale, window=window,
-            interpret=interpret)
-
-    @classmethod
-    def flash_chunk(cls, q, cache, offset, limit, *, scale, window=None,
-                    interpret=None, r=None):
-        # fp pool only: earlier chunks' pages are not quantized yet
-        from repro.kernels import flash_decode as fd
-        return fd.flash_chunk_paged_mla(q, cache['cl'], offset, limit,
-                                        cache['bt'], r=r, scale=scale,
-                                        window=window, interpret=interpret)
+            _padded_query(q, cache['cl']), cache['cl'], cache['clq'],
+            cache['cs'], pos,
+            _at(cache['bt'], layer), _at(cache['hw'], layer), r=r,
+            scale=scale, window=window, interpret=interpret, layer=layer)
 
     @classmethod
     def quantize_pages(cls, cache, pages):
@@ -318,68 +400,94 @@ class PagedMLAQ8Layout(CacheLayout):
 
 
 @_register
-class PagedMLALayout(CacheLayout):
+class PagedMLALayout(_PagedMLABase):
     """Paged MLA latent pool: one ``cl`` pool (ckv ‖ krope per row) +
     shared ``bt`` block tables. fp-only; the q8 twin adds the cold tier."""
     name = 'paged_mla'
     required = frozenset({'cl', 'bt'})
-    paged = True
-    mla = True
-    table_leaves = ('bt',)
     scrub_leaves = ('cl',)
     poison_leaves = ('cl',)
 
     @classmethod
-    def write_token(cls, cache, updates, pos):
-        lat = _latent_row(updates)
-        posv = _pos_vec(pos, lat.shape[0])
-        return dict(cache, cl=kvc.paged_token_update(cache['cl'], lat, posv,
-                                                     cache['bt']))
-
-    @classmethod
-    def write_prefill(cls, cache, updates):
-        return dict(cache, cl=kvc.paged_prefill_update(
-            cache['cl'], _latent_row(updates), cache['bt']))
-
-    @classmethod
-    def write_chunk(cls, cache, updates, offset, limit):
-        return dict(cache, cl=kvc.paged_chunk_update(
-            cache['cl'], _latent_row(updates), offset, limit, cache['bt']))
-
-    @classmethod
-    def gather(cls, cache, pos, r=None):
+    def gather(cls, cache, pos, r=None, layer=None, width=None):
         del pos
-        assert r is not None, 'MLA gathers need the static latent rank r'
-        dense = kvc.gather_pages(cache['cl'], cache['bt'])
-        return dense[..., :r], dense[..., r:]
+        return cls.gather_fp(cache, r=r, layer=layer, width=width)
 
     @classmethod
     def flash_decode(cls, q, cache, pos, *, scale, window=None,
-                     interpret=None, r=None):
+                     interpret=None, r=None, layer=None):
         from repro.kernels import flash_decode as fd
-        return fd.flash_decode_paged_mla(q, cache['cl'], pos, cache['bt'],
-                                         r=r, scale=scale, window=window,
-                                         interpret=interpret)
+        return fd.flash_decode_paged_mla(_padded_query(q, cache['cl']),
+                                         cache['cl'], pos,
+                                         _at(cache['bt'], layer), r=r,
+                                         scale=scale, window=window,
+                                         interpret=interpret, layer=layer)
+
+
+class _PagedGQABase(CacheLayout):
+    """Write ops and fp-pool reads shared by the two paged GQA layouts:
+    every write lands in the fp ``k``/``v`` pools."""
+    paged = True
+    table_leaves = ('bt',)
+
+    @classmethod
+    def write_token(cls, cache, updates, pos, layer=None):
+        posv = _pos_vec(pos, updates['k'].shape[0])
+        bt = _at(cache['bt'], layer)
+        return dict(
+            cache,
+            k=kvc.paged_token_update(cache['k'], updates['k'], posv, bt,
+                                     layer),
+            v=kvc.paged_token_update(cache['v'], updates['v'], posv, bt,
+                                     layer))
+
+    @classmethod
+    def write_prefill(cls, cache, updates, layer=None):
+        bt = _at(cache['bt'], layer)
+        return dict(
+            cache,
+            k=kvc.paged_prefill_update(cache['k'], updates['k'], bt, layer),
+            v=kvc.paged_prefill_update(cache['v'], updates['v'], bt, layer))
+
+    @classmethod
+    def write_chunk(cls, cache, updates, offset, limit, layer=None):
+        bt = _at(cache['bt'], layer)
+        return dict(
+            cache,
+            k=kvc.paged_chunk_update(cache['k'], updates['k'], offset,
+                                     limit, bt, layer),
+            v=kvc.paged_chunk_update(cache['v'], updates['v'], offset,
+                                     limit, bt, layer))
+
+    @classmethod
+    def gather_fp(cls, cache, r=None, layer=None, width=None):
+        del r
+        c = _layer_view(cache, layer)
+        return (_rows(kvc.gather_pages(c['k'], c['bt']), width),
+                _rows(kvc.gather_pages(c['v'], c['bt']), width))
 
     @classmethod
     def flash_chunk(cls, q, cache, offset, limit, *, scale, window=None,
-                    interpret=None, r=None):
+                    interpret=None, r=None, layer=None):
+        # fp pools only: earlier chunks' pages are not quantized yet
+        del r
         from repro.kernels import flash_decode as fd
-        return fd.flash_chunk_paged_mla(q, cache['cl'], offset, limit,
-                                        cache['bt'], r=r, scale=scale,
-                                        window=window, interpret=interpret)
+        out = fd.flash_chunk_paged(_padded_query(q, cache['k']), cache['k'],
+                                   cache['v'], offset, limit,
+                                   _at(cache['bt'], layer), scale=scale,
+                                   window=window, interpret=interpret,
+                                   layer=layer)
+        return out[..., :q.shape[-1]]
 
 
 @_register
-class PagedQ8Layout(CacheLayout):
+class PagedQ8Layout(_PagedGQABase):
     """Paged GQA pools + int8 cold tier: ``k``/``v``/``kq``/``vq``/``ks``/
     ``vs``/``bt``/``hw``. Writes land in the fp pools; aged-out pages are
     quantized per-page, per-head absmax."""
     name = 'paged_q8'
     required = frozenset({'k', 'v', 'kq', 'vq', 'ks', 'vs', 'bt', 'hw'})
-    paged = True
     quantized = True
-    table_leaves = ('bt',)
     quant_leaves = ('k', 'v', 'kq', 'vq', 'ks', 'vs')
     quant_probe = 'ks'
     quant_probe_ndim = 2
@@ -390,64 +498,23 @@ class PagedQ8Layout(CacheLayout):
                   'ks': (2, 1), 'vs': (2, 1)}
 
     @classmethod
-    def write_token(cls, cache, updates, pos):
-        posv = _pos_vec(pos, updates['k'].shape[0])
-        return dict(
-            cache,
-            k=kvc.paged_token_update(cache['k'], updates['k'], posv,
-                                     cache['bt']),
-            v=kvc.paged_token_update(cache['v'], updates['v'], posv,
-                                     cache['bt']))
-
-    @classmethod
-    def write_prefill(cls, cache, updates):
-        return dict(
-            cache,
-            k=kvc.paged_prefill_update(cache['k'], updates['k'],
-                                       cache['bt']),
-            v=kvc.paged_prefill_update(cache['v'], updates['v'],
-                                       cache['bt']))
-
-    @classmethod
-    def write_chunk(cls, cache, updates, offset, limit):
-        return dict(
-            cache,
-            k=kvc.paged_chunk_update(cache['k'], updates['k'], offset,
-                                     limit, cache['bt']),
-            v=kvc.paged_chunk_update(cache['v'], updates['v'], offset,
-                                     limit, cache['bt']))
-
-    @classmethod
-    def gather(cls, cache, pos, r=None):
+    def gather(cls, cache, pos, r=None, layer=None, width=None):
         del r
-        return kvq.dequant_gather(cache, _pos_vec(pos,
-                                                  cache['bt'].shape[0]))
-
-    @classmethod
-    def gather_fp(cls, cache, r=None):
-        del r
-        return (kvc.gather_pages(cache['k'], cache['bt']),
-                kvc.gather_pages(cache['v'], cache['bt']))
+        c = _layer_view(cache, layer)
+        return tuple(_rows(a, width) for a in kvq.dequant_gather(
+            c, _pos_vec(pos, c['bt'].shape[0])))
 
     @classmethod
     def flash_decode(cls, q, cache, pos, *, scale, window=None,
-                     interpret=None, r=None):
+                     interpret=None, r=None, layer=None):
         del r
         from repro.kernels import flash_decode as fd
-        return fd.flash_decode_paged_q8(
-            q, cache['k'], cache['v'], cache['kq'], cache['vq'],
-            cache['ks'], cache['vs'], pos, cache['bt'], cache['hw'],
-            scale=scale, window=window, interpret=interpret)
-
-    @classmethod
-    def flash_chunk(cls, q, cache, offset, limit, *, scale, window=None,
-                    interpret=None, r=None):
-        # fp pools only: earlier chunks' pages are not quantized yet
-        del r
-        from repro.kernels import flash_decode as fd
-        return fd.flash_chunk_paged(q, cache['k'], cache['v'], offset,
-                                    limit, cache['bt'], scale=scale,
-                                    window=window, interpret=interpret)
+        out = fd.flash_decode_paged_q8(
+            _padded_query(q, cache['k']), cache['k'], cache['v'],
+            cache['kq'], cache['vq'], cache['ks'], cache['vs'], pos,
+            _at(cache['bt'], layer), _at(cache['hw'], layer), scale=scale,
+            window=window, interpret=interpret, layer=layer)
+        return out[..., :q.shape[-1]]
 
     @classmethod
     def quantize_pages(cls, cache, pages):
@@ -455,67 +522,29 @@ class PagedQ8Layout(CacheLayout):
 
 
 @_register
-class PagedLayout(CacheLayout):
+class PagedLayout(_PagedGQABase):
     """Paged GQA pools: ``k``/``v`` pools + shared ``bt`` block tables."""
     name = 'paged'
     required = frozenset({'k', 'v', 'bt'})
-    paged = True
-    table_leaves = ('bt',)
     scrub_leaves = ('k', 'v')
     poison_leaves = ('k', 'v')
     shard_dims = {'k': (4, 2), 'v': (4, 2)}     # (P, ps, Hkv, dh): split Hkv
 
     @classmethod
-    def write_token(cls, cache, updates, pos):
-        posv = _pos_vec(pos, updates['k'].shape[0])
-        return dict(
-            cache,
-            k=kvc.paged_token_update(cache['k'], updates['k'], posv,
-                                     cache['bt']),
-            v=kvc.paged_token_update(cache['v'], updates['v'], posv,
-                                     cache['bt']))
-
-    @classmethod
-    def write_prefill(cls, cache, updates):
-        return dict(
-            cache,
-            k=kvc.paged_prefill_update(cache['k'], updates['k'],
-                                       cache['bt']),
-            v=kvc.paged_prefill_update(cache['v'], updates['v'],
-                                       cache['bt']))
-
-    @classmethod
-    def write_chunk(cls, cache, updates, offset, limit):
-        return dict(
-            cache,
-            k=kvc.paged_chunk_update(cache['k'], updates['k'], offset,
-                                     limit, cache['bt']),
-            v=kvc.paged_chunk_update(cache['v'], updates['v'], offset,
-                                     limit, cache['bt']))
-
-    @classmethod
-    def gather(cls, cache, pos, r=None):
-        del pos, r
-        return (kvc.gather_pages(cache['k'], cache['bt']),
-                kvc.gather_pages(cache['v'], cache['bt']))
+    def gather(cls, cache, pos, r=None, layer=None, width=None):
+        del pos
+        return cls.gather_fp(cache, r=r, layer=layer, width=width)
 
     @classmethod
     def flash_decode(cls, q, cache, pos, *, scale, window=None,
-                     interpret=None, r=None):
+                     interpret=None, r=None, layer=None):
         del r
         from repro.kernels import flash_decode as fd
-        return fd.flash_decode_paged(q, cache['k'], cache['v'], pos,
-                                     cache['bt'], scale=scale,
-                                     window=window, interpret=interpret)
-
-    @classmethod
-    def flash_chunk(cls, q, cache, offset, limit, *, scale, window=None,
-                    interpret=None, r=None):
-        del r
-        from repro.kernels import flash_decode as fd
-        return fd.flash_chunk_paged(q, cache['k'], cache['v'], offset,
-                                    limit, cache['bt'], scale=scale,
-                                    window=window, interpret=interpret)
+        out = fd.flash_decode_paged(_padded_query(q, cache['k']), cache['k'],
+                                    cache['v'], pos, _at(cache['bt'], layer),
+                                    scale=scale, window=window,
+                                    interpret=interpret, layer=layer)
+        return out[..., :q.shape[-1]]
 
 
 @_register
@@ -528,28 +557,25 @@ class ContiguousMLALayout(CacheLayout):
     mla = True
 
     @classmethod
-    def write_token(cls, cache, updates, pos):
+    def write_token(cls, cache, updates, pos, layer=None):
         return dict(cache,
                     ckv=dense_token_update(cache['ckv'], updates['ckv'],
-                                           pos),
+                                           pos, layer),
                     krope=dense_token_update(cache['krope'],
-                                             updates['krope'], pos))
+                                             updates['krope'], pos, layer))
 
     @classmethod
-    def write_prefill(cls, cache, updates):
+    def write_prefill(cls, cache, updates, layer=None):
         return dict(
             cache,
-            ckv=jax.lax.dynamic_update_slice(
-                cache['ckv'], updates['ckv'].astype(cache['ckv'].dtype),
-                (0, 0, 0)),
-            krope=jax.lax.dynamic_update_slice(
-                cache['krope'],
-                updates['krope'].astype(cache['krope'].dtype), (0, 0, 0)))
+            ckv=_dense_prefill(cache['ckv'], updates['ckv'], layer),
+            krope=_dense_prefill(cache['krope'], updates['krope'], layer))
 
     @classmethod
-    def gather(cls, cache, pos, r=None):
-        del pos, r
-        return cache['ckv'], cache['krope']
+    def gather(cls, cache, pos, r=None, layer=None, width=None):
+        del pos, r, width           # ckv and krope are stored unpadded
+        c = _layer_view(cache, layer)
+        return c['ckv'], c['krope']
 
 
 @_register
@@ -560,34 +586,34 @@ class ContiguousLayout(CacheLayout):
     shard_dims = {'k': (4, 2), 'v': (4, 2)}     # (B, S, Hkv, dh): split Hkv
 
     @classmethod
-    def write_token(cls, cache, updates, pos):
+    def write_token(cls, cache, updates, pos, layer=None):
         return dict(cache,
-                    k=dense_token_update(cache['k'], updates['k'], pos),
-                    v=dense_token_update(cache['v'], updates['v'], pos))
+                    k=dense_token_update(cache['k'], updates['k'], pos,
+                                         layer),
+                    v=dense_token_update(cache['v'], updates['v'], pos,
+                                         layer))
 
     @classmethod
-    def write_prefill(cls, cache, updates):
-        return dict(
-            cache,
-            k=jax.lax.dynamic_update_slice(
-                cache['k'], updates['k'].astype(cache['k'].dtype),
-                (0, 0, 0, 0)),
-            v=jax.lax.dynamic_update_slice(
-                cache['v'], updates['v'].astype(cache['v'].dtype),
-                (0, 0, 0, 0)))
+    def write_prefill(cls, cache, updates, layer=None):
+        return dict(cache,
+                    k=_dense_prefill(cache['k'], updates['k'], layer),
+                    v=_dense_prefill(cache['v'], updates['v'], layer))
 
     @classmethod
-    def gather(cls, cache, pos, r=None):
+    def gather(cls, cache, pos, r=None, layer=None, width=None):
         del pos, r
-        return cache['k'], cache['v']
+        c = _layer_view(cache, layer)
+        return _rows(c['k'], width), _rows(c['v'], width)
 
     @classmethod
     def flash_decode(cls, q, cache, pos, *, scale, window=None,
-                     interpret=None, r=None):
+                     interpret=None, r=None, layer=None):
         del r
         from repro.kernels import flash_decode as fd
-        return fd.flash_decode(q, cache['k'], cache['v'], pos, scale=scale,
-                               window=window, interpret=interpret)
+        out = fd.flash_decode(_padded_query(q, cache['k']), cache['k'],
+                              cache['v'], pos, scale=scale, window=window,
+                              interpret=interpret, layer=layer)
+        return out[..., :q.shape[-1]]
 
 
 @_register

@@ -10,6 +10,7 @@ The topology is described inside a module fixture — never at import time
 this file loads the TPU library."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +40,10 @@ def topo():
 
 @pytest.fixture(scope='module')
 def compile_tpu(topo):
-    """``compile_tpu(fn, *(shape, dtype))`` -> compiled HLO text of ``fn``
-    for one described v5e chip (shapes only; nothing runs). The persistent
-    compilation cache stays off: a described-chip compile cannot be read
-    back without the chip."""
+    """``compile_tpu(fn, *(shape, dtype), **jit_kwargs)`` -> compiled HLO
+    text of ``fn`` for one described v5e chip (shapes only; nothing runs).
+    The persistent compilation cache stays off: a described-chip compile
+    cannot be read back without the chip."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -50,10 +51,10 @@ def compile_tpu(topo):
     jax.config.update('jax_enable_compilation_cache', False)
     compilation_cache.reset_cache()
 
-    def compile_(fn, *specs):
+    def compile_(fn, *specs, **jit_kwargs):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in specs]
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn, **jit_kwargs).lower(*args).compile().as_text()
 
     yield compile_
     jax.config.update('jax_enable_compilation_cache', was)
@@ -209,6 +210,138 @@ def test_stablelm_decode_step_full_width(compile_tpu, monkeypatch, mode,
 
     txt = compile_tpu(flat_step, ((slots,), I32), ((slots,), I32), *specs)
     assert txt.count('tpu_custom_call') >= (2 if mode == 'w8a8' else 1)
+
+
+_HLO_OP = re.compile(r'^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\{[^}]*\} '
+                     r'([\w-]+)\(')
+_ALIAS = re.compile(r'\{(\d+)\}: \((\d+), \{\}')
+
+
+def _assert_step_updates_pools_in_place(compile_tpu, monkeypatch, cfg, step,
+                                        mode, kv_quant, *, slots, max_blocks,
+                                        prompt):
+    """Compile ``cfg``'s decode or prefill step for a described v5e the
+    way ``serve_continuous`` runs it — 8-row pages, ``1 + slots *
+    max_blocks`` of them, the pools' rows padded to whole lanes as on a
+    TPU (``kv_cache.row_width``), the cache donated — and assert that no
+    ``copy``, dynamic-slice or dynamic-update-slice fusion or fresh
+    ``AllocateBuffer`` is shaped like a stacked pool leaf or like one
+    layer's, or leads with the (layers, pages) dims of every stacked
+    per-page leaf (pools and the int8 tier's scales), and that every pool
+    comes back in its donated buffer."""
+    from repro.core import yoco_linear
+    from repro.core.yoco_linear import YocoConfig
+    from repro.models import model as model_mod
+    from repro.models.model import ModelRuntime
+    from repro.runtime import kv_cache as kvc
+    from repro.runtime import layouts
+    from repro.runtime import serve_step as SS
+
+    monkeypatch.setattr(ops, '_interpret', lambda: False)
+    monkeypatch.setattr(kvc, '_lanes', lambda: kvc.LANES)
+    ps = 8
+
+    def make_params(key):
+        p = model_mod.init_params(key, cfg)
+        return yoco_linear.quantize_tree(p) if mode == 'w8a8' else p
+
+    params = jax.eval_shape(make_params, jax.random.key(0))
+    cache = jax.eval_shape(lambda: model_mod.init_paged_cache_tree(
+        cfg, slots if step == 'decode' else 1,
+        num_pages=1 + slots * max_blocks, page_size=ps,
+        max_blocks=max_blocks, kv_dtype='int8' if kv_quant else None,
+        hot_window=2))
+    layer = cache['layers']
+    assert layouts.get_layout(layer).name == ('paged_q8' if kv_quant
+                                              else 'paged')
+    pools = [k for k in ('k', 'v', 'kq', 'vq') if k in layer]
+    assert all(layer[k].shape[-1] % kvc.LANES == 0 for k in pools)
+    shapes = {s for k in pools
+              for s in (layer[k].shape, layer[k].shape[1:])}
+    per_page = layer['k'].shape[:2]                       # (L, P)
+    yoco = YocoConfig(mode=mode, use_pallas=True)
+    rt = ModelRuntime(attn_impl='flash')
+    if step == 'decode':
+        body = SS.make_decode_step(cfg, yoco, rt)
+        lead = [((slots,), I32), ((slots,), I32)]        # tokens, positions
+        n_out_lead = 2                                   # tokens, logits
+    else:
+        prefill = SS.make_prefill_step(cfg, yoco, rt)
+
+        def body(p, toks, c, last_pos):
+            return prefill(p, dict(inputs=toks), c, last_pos)
+        lead = [((1, prompt), I32)]                       # padded prompt
+        n_out_lead = 1                                    # logits
+    p_leaves, c_leaves = jax.tree.leaves(params), jax.tree.leaves(cache)
+    p0 = len(lead)
+    c0 = p0 + len(p_leaves)
+
+    def flat_step(*args):
+        p = jax.tree.unflatten(jax.tree.structure(params), args[p0:c0])
+        c = jax.tree.unflatten(jax.tree.structure(cache),
+                               args[c0:c0 + len(c_leaves)])
+        return body(p, *args[:p0], c, *args[c0 + len(c_leaves):])
+
+    tail = [] if step == 'decode' else [((1,), I32)]      # last position
+    txt = compile_tpu(
+        flat_step, *lead, *[(a.shape, a.dtype) for a in p_leaves],
+        *[(a.shape, a.dtype) for a in c_leaves], *tail,
+        donate_argnums=tuple(range(c0, c0 + len(c_leaves))))
+
+    copies = []
+    for line in txt.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        name, dims, op = m.groups()
+        shape = tuple(int(d) for d in dims.split(',') if d)
+        if (shape in shapes or shape[:2] == per_page) and (
+                op == 'copy' or (op == 'fusion' and 'dynamic' in name)
+                or 'AllocateBuffer' in line):
+            copies.append(line.strip()[:160])
+    assert not copies, copies
+
+    header = next(l for l in txt.splitlines() if l.startswith('HloModule'))
+    aliased = {int(o): int(p) for o, p in _ALIAS.findall(
+        header.split('input_output_alias=')[1].split('entry_computation')[0])}
+    keys = sorted(layer)                   # flattening order of the dict
+    for key in pools:
+        i = keys.index(key)
+        assert aliased.get(n_out_lead + i) == c0 + i, (key, aliased)
+
+
+@pytest.mark.parametrize('step', ['decode', 'prefill'])
+@pytest.mark.parametrize('mode,kv_quant', [('bf16', False), ('bf16', True),
+                                           ('w8a8', False)])
+def test_stablelm_step_updates_pools_in_place(compile_tpu, monkeypatch,
+                                              step, mode, kv_quant):
+    """The layer scan reads and writes the stacked paged pools in place:
+    stablelm-1.6b at its published widths (head dim 64, stored 128 wide),
+    4 lanes of 128 pages — 513 pages, a pool size at which the chip's
+    default layout for unpadded 64-wide rows puts the page axis minor-most,
+    so pools that lost their padding would be re-laid-out (copied)
+    here."""
+    from repro import configs
+    _assert_step_updates_pools_in_place(
+        compile_tpu, monkeypatch, configs.get('stablelm-1.6b', smoke=False),
+        step, mode, kv_quant, slots=4, max_blocks=128, prompt=128)
+
+
+@pytest.mark.parametrize('step', ['decode', 'prefill'])
+def test_stablelm_12b_step_updates_pools_in_place(compile_tpu, monkeypatch,
+                                                  step):
+    """The same at stablelm-12b's widths as its cells serve it (8 KV heads
+    of 160, stored 256 wide; w8a8 with the int8 KV tier), at the offline
+    cell's pool: 36 lanes of 512 pages, 18433 pages. Two of the layers
+    keep the compile short; the pools' layout does not depend on depth."""
+    import dataclasses
+
+    from repro import configs
+    cfg = dataclasses.replace(configs.get('stablelm-12b', smoke=False),
+                              n_layers=2)
+    _assert_step_updates_pools_in_place(
+        compile_tpu, monkeypatch, cfg, step, 'w8a8', True, slots=36,
+        max_blocks=512, prompt=128)
 
 
 def test_stablelm_tp4_decode_step_full_width(topo, compile_tpu, monkeypatch):
