@@ -13,7 +13,15 @@ Every file of a cell is found by a name in ``BENCHMARK.json``:
   returning a number or ``None`` (nothing to read: the metric is left
   out). A name with dotted parts falls back to the reader of its longest
   dotted prefix that has a file, so ``device.idle.chat`` and
-  ``device.idle.w8a8`` share ``device.idle.py``.
+  ``device.idle.w8a8`` share ``device.idle.py``;
+- ``chipbench/arch/<model_type>.py`` (named by the ``model_type`` in the
+  configuration's ``model``): the architecture's ``ArchConfig``, plain
+  reference and counts, as ``harness/arch.py`` states.
+
+So the cell of a new architecture adds files and edits none: its
+``arch/<model_type>.py``, ``configs/<config>.json`` and
+``cells/<cell>.json``; a ``traffic/<mix>.json`` or ``metrics/<metric>.py``
+only where no file there serves; and its entries in ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -96,21 +104,12 @@ def read_metric(name: str, ctx: dict):
 
 
 def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for a configuration file, registered
-    under the configuration's name so ``serve_continuous`` can look it up
-    (the file is the configuration as run)."""
+    """The program's ``ArchConfig`` for a configuration file, built by its
+    architecture's module and registered under the configuration's name so
+    ``serve_continuous`` can look it up."""
     from repro.configs import base
-    c = conf['model']
-    cfg = base.ArchConfig(
-        name=conf['name'], family='dense',
-        n_layers=c['num_hidden_layers'], d_model=c['hidden_size'],
-        n_heads=c['num_attention_heads'],
-        n_kv_heads=c['num_key_value_heads'], d_ff=c['intermediate_size'],
-        vocab_size=c['vocab_size'], rope_theta=float(c['rope_theta']),
-        rope_fraction=float(c['partial_rotary_factor']),
-        mlp_type={'silu': 'swiglu'}[c['hidden_act']],
-        norm_type='layernorm', max_seq_len=c['max_position_embeddings'],
-        source=conf['source'])
+    from chipbench.harness import arch
+    cfg = arch.of(conf['model']).arch_config(conf)
     base.register(cfg, cfg)
     return cfg
 

@@ -26,9 +26,10 @@ def gaps(seed: int, model: dict, sample, *, pad_to: int,
     """Per served token: (f32 gap of the served token, f32 gap of the
     control's first choice or None), one sequence at a time at the fixed
     length ``pad_to``."""
-    from chipbench.harness import reference as ref
+    from chipbench.harness import arch
     if not sample:
         return np.zeros(0), None
+    ref = arch.of(model)
     served, ctrl = [], []
     for r in sample:
         seq = np.zeros((1, pad_to), np.int32)

@@ -5,8 +5,8 @@ import pytest
 
 from conftest import SMOKE_MODEL
 
-MODEL = dict(SMOKE_MODEL, partial_rotary_factor=0.25, rope_theta=10000,
-             layer_norm_eps=1e-5, max_position_embeddings=4096,
+MODEL = dict(SMOKE_MODEL, model_type='stablelm', partial_rotary_factor=0.25,
+             rope_theta=10000, layer_norm_eps=1e-5, max_position_embeddings=4096,
              hidden_act='silu')
 
 

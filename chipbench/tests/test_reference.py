@@ -12,7 +12,7 @@ def pair():
     import jax
     from repro.configs import base
     from repro.models import model as model_mod
-    from chipbench.harness import reference as ref
+    from chipbench.harness import arch
     c = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
              num_key_value_heads=2, intermediate_size=160, vocab_size=256,
              partial_rotary_factor=0.25, rope_theta=10000,
@@ -23,6 +23,7 @@ def pair():
         n_kv_heads=2, d_ff=160, vocab_size=256, rope_fraction=0.25,
         norm_type='layernorm', max_seq_len=4096)
     seed = 123456789
+    ref = arch.module('stablelm')
     return c, cfg, seed, model_mod.init_params(jax.random.key(seed), cfg), ref
 
 
